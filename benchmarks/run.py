@@ -26,6 +26,8 @@ import json
 import sys
 import traceback
 
+from repro.compile_cache import use_compile_cache
+
 BENCH_SCHEMA_VERSION = 2
 
 #: acceptance bars on a bench's ``derived`` value (see each bench's
@@ -86,6 +88,7 @@ def main() -> None:
                   "registered bench (see --list)", file=sys.stderr)
             raise SystemExit(2)
 
+    use_compile_cache()
     print("name,us_per_call,derived")
     report = {}
     failures = 0

@@ -26,6 +26,7 @@ import json
 import time
 from dataclasses import replace
 
+from repro.compile_cache import use_compile_cache
 from repro.core.api import paper_spec, sweep
 
 JAX_SPEEDUP_BAR = 3.0
@@ -99,6 +100,7 @@ def main():
                          "(bar/pass included)")
     args = ap.parse_args()
     use_pallas = {"auto": None, "on": True, "off": False}[args.pallas]
+    use_compile_cache()
     print("name,us_per_call,derived")
     cold_per, warm_per, numpy_per, sw = time_jax_sweep(
         args.lanes, args.duration, use_pallas=use_pallas,

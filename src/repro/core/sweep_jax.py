@@ -65,7 +65,40 @@ from repro.core import timeline as timeline_registry
 from repro.core.spec import CampaignSpec
 from repro.core.sweep import _Lane, _THRESHOLDS, _prepare
 
-__all__ = ["JaxLaneOps", "JaxSweepEngine", "run_jax_detailed", "run_jax"]
+__all__ = ["JaxLaneOps", "JaxSweepEngine", "run_jax_detailed", "run_jax",
+           "STAT_BANDS", "band_violations"]
+
+#: the statistical-equivalence contract (README "Simulation engines"):
+#: metric -> relative tolerance on the per-scenario mean against the
+#: batched engine (and band-widening margin).  ``preemptions`` is
+#: deliberately looser: this engine kills proportionally across
+#: occupancy cells where the row engines kill newest-first, which
+#: shifts how many of a tick's kills land on busy instances without
+#: moving cost/throughput.
+STAT_BANDS = {"cost": 0.02, "accel_days": 0.02, "jobs_finished": 0.02,
+              "preemptions": 0.25, "egress_usd": 0.05}
+
+
+def band_violations(ref: Dict[str, dict], got: Dict[str, dict],
+                    bands: Optional[Dict[str, float]] = None) -> List[tuple]:
+    """Where a ``SweepResult.summary`` ``got`` leaves the reference
+    summary ``ref``'s bands: per scenario and metric, the means must
+    agree within ``rel * |ref mean|`` and got's [p5, p95] must lie
+    inside ref's widened by the same margin.  Returns
+    ``(scenario, metric, "mean" | "band", ref stats, got stats)``
+    tuples, empty when every band holds."""
+    bands = STAT_BANDS if bands is None else bands
+    out = []
+    for scen in sorted(ref):
+        for metric, rel in bands.items():
+            a, b = ref[scen][metric], got[scen][metric]
+            margin = rel * max(abs(a["mean"]), 1e-9)
+            if abs(b["mean"] - a["mean"]) > margin:
+                out.append((scen, metric, "mean", a, b))
+            if not (a["p5"] - margin <= b["p5"]
+                    and b["p95"] <= a["p95"] + margin):
+                out.append((scen, metric, "band", a, b))
+    return out
 
 
 class JaxLaneOps:
@@ -231,6 +264,12 @@ def _poisson(u, lam):
                      jnp.maximum(k_norm, 0.0).astype(jnp.int32), kk)
 
 
+#: precision of the count-plane matmuls (counts and $ against one-hot
+#: maps): a default-precision f32 matmul on a TPU's MXU rounds its
+#: inputs to bf16, which is exact for counts only up to 256
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 @functools.partial(jax.jit, static_argnames=("nat_any", "use_pallas",
                                              "dp_gating", "dp_staging"))
 def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
@@ -265,7 +304,7 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
 
     def requeue_levels(kb):
         # busy cells [B,G,W] -> checkpoint-level counts [B,L]
-        return jnp.matmul(kb.astype(jnp.float32), M_wl) \
+        return jnp.matmul(kb.astype(jnp.float32), M_wl, precision=_EXACT) \
             .sum(axis=1).astype(jnp.int32)
 
     def split_cells(idle, pdead, busy, k):
@@ -417,7 +456,8 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
             eg_g = (take_f - th_f) * consts["dp_usd_miss_g"][None, :]
             egress_g = c["egress_g"] + eg_g
         else:
-            busy = busy + jnp.matmul(joint, M_jw).astype(jnp.int32)
+            busy = busy + jnp.matmul(joint, M_jw, precision=_EXACT) \
+                .astype(jnp.int32)
             hit_acc, hits, misses = c["hit_acc"], c["hits"], c["misses"]
             stage_t, egress_g = c["stage_t"], c["egress_g"]
             eg_g = jnp.zeros_like(egress_g)
@@ -471,7 +511,8 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
         live_end = (idle + pdead).astype(jnp.float32) + busy_g
         accel = c["accel"] + live_end.sum(axis=1) * dt
         busy_h = c["busy_h"] + busy_g.sum(axis=1) * dt
-        busy_prov = c["busy_prov"] + (busy_g @ prov_onehot) * dt
+        busy_prov = c["busy_prov"] \
+            + jnp.matmul(busy_g, prov_onehot, precision=_EXACT) * dt
 
         return {"idle": idle, "pdead": pdead, "busy": busy,
                 "target_g": target_g, "lv": lv, "fresh_q": fresh_q,
@@ -517,7 +558,8 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
     live_final = out["idle"] + out["pdead"] + out["busy"].sum(axis=2)
     amt = live_final.astype(jnp.float32) * planes["rate"][-1] * dt
     out["spent"] = out["spent"] + amt.sum(axis=1)
-    out["by_prov"] = out["by_prov"] + amt @ prov_onehot
+    out["by_prov"] = out["by_prov"] \
+        + jnp.matmul(amt, prov_onehot, precision=_EXACT)
     out["live_g"] = live_final
     return out
 
@@ -774,16 +816,26 @@ class JaxSweepEngine:
             "sweep lanes need a budget"
         self.out: Optional[dict] = None
 
-    def run(self) -> "JaxSweepEngine":
+    def _scan_call(self):
         xs = (np.arange(self.N, dtype=np.int32),
               self.seg_of_tick,
               self.is_seg_start)
-        out = _scan_campaigns(
-            {k: jnp.asarray(v) for k, v in self.planes.items()},
-            {k: jnp.asarray(v) for k, v in self.consts.items()},
-            tuple(jnp.asarray(v) for v in xs),
-            nat_any=self.nat_any, use_pallas=self.use_pallas,
-            dp_gating=self.dp_active, dp_staging=self.dp_staging)
+        args = ({k: jnp.asarray(v) for k, v in self.planes.items()},
+                {k: jnp.asarray(v) for k, v in self.consts.items()},
+                tuple(jnp.asarray(v) for v in xs))
+        return args, dict(nat_any=self.nat_any, use_pallas=self.use_pallas,
+                          dp_gating=self.dp_active,
+                          dp_staging=self.dp_staging)
+
+    def lower(self):
+        """The scan exactly as :meth:`run` dispatches it, lowered:
+        ``.compile().as_text()`` is the program the device runs."""
+        args, kw = self._scan_call()
+        return _scan_campaigns.lower(*args, **kw)
+
+    def run(self) -> "JaxSweepEngine":
+        args, kw = self._scan_call()
+        out = _scan_campaigns(*args, **kw)
         self.out = {k: np.asarray(v) for k, v in out.items()}
         return self
 

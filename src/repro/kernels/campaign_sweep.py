@@ -15,9 +15,10 @@ phases over those planes:
   * ``campaign_bill_kernel``    — the billing/ledger reduction.
 
 Preempt and match share one body: a *systematic proportional integer
-allocator* (cumulative largest-remainder rounding).  One cumsum, then
-``floor(inclusive * k/tot) - floor(exclusive * k/tot)`` splits ``k``
-units across cells proportionally, exactly and deterministically.
+allocator* (cumulative largest-remainder rounding).  One inclusive
+prefix sum, then ``floor(inclusive * k/tot) - floor(exclusive * k/tot)``
+splits ``k`` units across cells proportionally, exactly and
+deterministically.
 
 TPU adaptation notes:
   * the grid tiles the row axis only (``block_r`` rows per program); a
@@ -26,6 +27,13 @@ TPU adaptation notes:
   * counts travel as int32 (Pallas TPU has no first-class bool tiles)
     and the allocator's scale factor rides in f32 — cumulative counts
     stay far below 2**24, so the f32 floors are exact,
+  * the prefix sum is a log-step scan over the cell (lane) axis:
+    ``pltpu.roll`` (``jnp.roll`` direction) plus an iota mask, in int32
+    — Mosaic has no ``cumsum`` lowering,
+  * the scale ``min(k, tot) / tot`` arrives precomputed: the ops.py
+    wrapper computes it with the oracle's own XLA expression, so
+    kernel == oracle does not depend on Mosaic and XLA rounding an f32
+    division alike,
   * the advance shift avoids gathers: ``lax.roll`` + an iota mask on
     the step axis,
   * like flash_attention, CPU/CI runs use ``interpret=True`` via the
@@ -40,21 +48,25 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _alloc_body(c_ref, k_ref, o_ref):
+def _alloc_body(c_ref, s_ref, o_ref):
     counts = c_ref[...]                                # (br, C) i32
-    tot = counts.sum(axis=-1, keepdims=True)
-    kk = jnp.minimum(k_ref[...], tot)                  # (br, 1) i32
-    s = kk.astype(jnp.float32) \
-        / jnp.maximum(tot, 1).astype(jnp.float32)
-    inc = jnp.cumsum(counts, axis=-1).astype(jnp.float32)
-    exc = inc - counts.astype(jnp.float32)
-    o_ref[...] = (jnp.floor(inc * s + 1e-3)
-                  - jnp.floor(exc * s + 1e-3)).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, counts.shape, 1)
+    inc = counts
+    d = 1
+    while d < counts.shape[1]:          # inclusive scan, log2(C) rounds
+        inc = inc + jnp.where(lane >= d, pltpu.roll(inc, d, 1), 0)
+        d *= 2
+    s = s_ref[...]                                     # (br, 1) f32
+    inc_f = inc.astype(jnp.float32)
+    exc_f = inc_f - counts.astype(jnp.float32)
+    o_ref[...] = (jnp.floor(inc_f * s + 1e-3)
+                  - jnp.floor(exc_f * s + 1e-3)).astype(jnp.int32)
 
 
-def _alloc_call(counts, k, *, block_r, interpret):
+def _alloc_call(counts, scale, *, block_r, interpret):
     R, C = counts.shape
     spec = pl.BlockSpec((block_r, C), lambda i: (i, 0))
     return pl.pallas_call(
@@ -63,20 +75,21 @@ def _alloc_call(counts, k, *, block_r, interpret):
         in_specs=[spec, pl.BlockSpec((block_r, 1), lambda i: (i, 0))],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.int32),
-        interpret=interpret)(counts, k)
+        interpret=interpret)(counts, scale)
 
 
-def campaign_preempt_kernel(counts, k, *, block_r, interpret=False):
-    """counts (R,C) i32 occupancy cells per (lane, group) row, k (R,1)
-    i32 sampled preemption counts -> killed (R,C) i32 (proportional
-    systematic split, killed <= counts, rows sum to min(k, total))."""
-    return _alloc_call(counts, k, block_r=block_r, interpret=interpret)
+def campaign_preempt_kernel(counts, scale, *, block_r, interpret=False):
+    """counts (R,C) i32 occupancy cells per (lane, group) row, scale
+    (R,1) f32 ``min(k, total) / total`` for the sampled preemption
+    counts k -> killed (R,C) i32 (proportional systematic split,
+    killed <= counts, rows sum to min(k, total))."""
+    return _alloc_call(counts, scale, block_r=block_r, interpret=interpret)
 
 
-def campaign_match_kernel(idle, k, *, block_r, interpret=False):
-    """idle (B,G) i32 idle-pilot counts, k (B,1) i32 matched jobs per
-    lane -> take (B,G) i32 (same allocator over lane rows)."""
-    return _alloc_call(idle, k, block_r=block_r, interpret=interpret)
+def campaign_match_kernel(idle, scale, *, block_r, interpret=False):
+    """idle (B,G) i32 idle-pilot counts, scale (B,1) f32 for the matched
+    jobs per lane -> take (B,G) i32 (same allocator over lane rows)."""
+    return _alloc_call(idle, scale, block_r=block_r, interpret=interpret)
 
 
 def _advance_body(b_ref, f_ref, a_ref, n_ref):
@@ -109,8 +122,11 @@ def campaign_advance_kernel(busy, fin_mask, *, block_r, interpret=False):
 def _bill_body(l_ref, r_ref, p_ref, s_ref, o_ref):
     amt = l_ref[...].astype(jnp.float32) * r_ref[...]  # (br, G)
     s_ref[...] = amt.sum(axis=-1, keepdims=True)
+    # HIGHEST: a default-precision f32 matmul on the MXU rounds amt to
+    # bf16, and by-provider totals would drift from spent
     o_ref[...] = jax.lax.dot_general(
         amt, p_ref[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

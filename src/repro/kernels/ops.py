@@ -1,5 +1,6 @@
 """jit'd public wrappers around the Pallas kernels: model-layout adapters,
-MXU-alignment padding, and interpret-mode fallback on CPU.
+MXU-alignment padding, and the interpret-mode policy
+(``sharding_ctx.default_interpret``: native on TPU, interpreted elsewhere).
 
 ``flash_attention`` plugs into models/attention.py via the flash_fn hook
 (RunConfig.attention_impl == "pallas"); the others are drop-in replacements
@@ -20,11 +21,8 @@ from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.mamba_scan import mamba_scan_kernel
 from repro.kernels.mlstm_chunk import mlstm_chunk_kernel
 from repro.kernels.moe_gmm import moe_gmm_kernel
-from repro.sharding_ctx import default_interpret, on_tpu
-
-
-def _on_tpu():
-    return on_tpu()
+from repro.kernels.ref import campaign_alloc_scale
+from repro.sharding_ctx import default_interpret
 
 
 def _pad_to(x, axis, mult):
@@ -41,7 +39,7 @@ def _pad_to(x, axis, mult):
 def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
                     interpret=None):
     """Model layout: q (B,Sq,H,D), k/v (B,Skv,Hkv,D) -> (B,Sq,H,D)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = default_interpret(interpret)
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     G = H // Hkv
@@ -70,7 +68,7 @@ def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
 def mamba_scan(xc, dt, bm, cm, a, *, block_d=128, block_s=64,
                interpret=None):
     """xc/dt: (B,S,di); bm/cm: (B,S,N); a: (di,N) -> y (B,S,di)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = default_interpret(interpret)
     B, S, di = xc.shape
     bd = min(block_d, di)
     bs = min(block_s, S)
@@ -90,7 +88,7 @@ def mamba_scan(xc, dt, bm, cm, a, *, block_d=128, block_s=64,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def mlstm_chunk(q, k, v, logi, logf, *, block_s=128, interpret=None):
     """q/k: (BH,S,dqk); v: (BH,S,dv); gates (BH,S,1) -> h (BH,S,dv)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = default_interpret(interpret)
     S = q.shape[1]
     bs = min(block_s, S)
     assert S % bs == 0, "pad sequence to a chunk multiple upstream"
@@ -102,7 +100,7 @@ def mlstm_chunk(q, k, v, logi, logf, *, block_s=128, interpret=None):
                                              "interpret"))
 def moe_gmm(x, w, *, block_c=128, block_f=128, block_k=128, interpret=None):
     """x: (E,C,D) @ w: (E,D,F) -> (E,C,F), fp32 accumulation."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = default_interpret(interpret)
     E, C, D = x.shape
     F = w.shape[2]
     bc, bf, bk = min(block_c, C), min(block_f, F), min(block_k, D)
@@ -116,8 +114,8 @@ def moe_gmm(x, w, *, block_c=128, block_f=128, block_k=128, interpret=None):
 # -- campaign-sweep tick ops (core/sweep_jax.py) ---------------------------
 # Same contract as the model kernels above: the wrapper owns layout
 # padding (cell axis to a VPU lane multiple, row axis to the row-block)
-# and the interpret-mode fallback; kernels/ref.py holds the jnp oracles
-# the jitted engine runs on CPU.
+# and the interpret-mode policy; kernels/ref.py holds the jnp oracles
+# (and the allocator scale both sides share).
 
 def _pad2(x, block_r, c_mult=128):
     x, _ = _pad_to(x, 0, block_r)
@@ -133,10 +131,10 @@ def campaign_preempt(counts, k, *, block_r=8, interpret=None):
     interpret = default_interpret(interpret)
     R, C = counts.shape
     br = min(block_r, R)
-    kp = _pad_to(k.astype(jnp.int32)[:, None], 0, br)[0]
-    killed = campaign_preempt_kernel(
-        _pad2(counts.astype(jnp.int32), br), kp,
-        block_r=br, interpret=interpret)
+    counts = counts.astype(jnp.int32)
+    sp = _pad_to(campaign_alloc_scale(counts, k)[:, None], 0, br)[0]
+    killed = campaign_preempt_kernel(_pad2(counts, br), sp,
+                                     block_r=br, interpret=interpret)
     return killed[:R, :C]
 
 
@@ -147,10 +145,10 @@ def campaign_match(idle, k, *, block_r=8, interpret=None):
     interpret = default_interpret(interpret)
     B, G = idle.shape
     br = min(block_r, B)
-    kp = _pad_to(k.astype(jnp.int32)[:, None], 0, br)[0]
-    take = campaign_match_kernel(
-        _pad2(idle.astype(jnp.int32), br), kp,
-        block_r=br, interpret=interpret)
+    idle = idle.astype(jnp.int32)
+    sp = _pad_to(campaign_alloc_scale(idle, k)[:, None], 0, br)[0]
+    take = campaign_match_kernel(_pad2(idle, br), sp,
+                                 block_r=br, interpret=interpret)
     return take[:B, :G]
 
 

@@ -91,14 +91,20 @@ def moe_gmm_ref(x, w):
 # these jnp forms directly on CPU and swaps in the Pallas kernels
 # (kernels/campaign_sweep.py) on TPU; test_kernels.py pins kernel == ref.
 
+def campaign_alloc_scale(counts, k):
+    """The allocator's per-row scale ``min(k, tot) / tot`` as (R,) f32
+    (the Pallas wrapper feeds the kernel this same expression)."""
+    tot = counts.sum(axis=-1)
+    kk = jnp.minimum(k, tot)
+    return kk.astype(jnp.float32) / jnp.maximum(tot, 1).astype(jnp.float32)
+
+
 def campaign_alloc_ref(counts, k):
     """Proportional integer allocator: counts (R,C) i32 non-negative,
     k (R,) i32 -> take (R,C) i32 with 0 <= take <= counts and
     ``take.sum(-1) == min(k, counts.sum(-1))``.  Systematic (cumulative
     largest-remainder) rounding: exact, deterministic, one cumsum."""
-    tot = counts.sum(axis=-1)
-    kk = jnp.minimum(k, tot)
-    s = kk.astype(jnp.float32) / jnp.maximum(tot, 1).astype(jnp.float32)
+    s = campaign_alloc_scale(counts, k)
     inc = jnp.cumsum(counts, axis=-1).astype(jnp.float32)
     exc = inc - counts.astype(jnp.float32)
     return (jnp.floor(inc * s[:, None] + 1e-3)
@@ -137,4 +143,5 @@ def campaign_bill_ref(live, rate, prov_onehot):
     rate (B,G) f32 ($ owed per instance this interval), prov_onehot
     (G,P) -> (spent (B,) f32, by_provider (B,P) f32)."""
     amt = live.astype(jnp.float32) * rate
-    return amt.sum(axis=-1), amt @ prov_onehot
+    return amt.sum(axis=-1), jnp.matmul(
+        amt, prov_onehot, precision=jax.lax.Precision.HIGHEST)

@@ -85,8 +85,6 @@ def run_cell(arch, shape_name, *, multi_pod=False, run_overrides=None,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older JAX: list of one dict
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     parsed = hlo_an.analyze(hlo_text)
     roof = rl.compute_roofline(cfg, shape, n_chips,

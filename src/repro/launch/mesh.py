@@ -32,4 +32,4 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = len(jax.devices())
     if shape is None:
         shape = (n, 1) if len(axes) == 2 else (n,)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

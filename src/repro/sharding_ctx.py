@@ -16,54 +16,33 @@ Logical axes:
 from __future__ import annotations
 
 import contextlib
-import inspect
 import threading
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
 
-# --------------------------------------------------------------------------
-# JAX version compatibility (the installed JAX moved these APIs around):
-#   * AbstractMesh: old signature is ``AbstractMesh(((name, size), ...))``,
-#     new signature is ``AbstractMesh(axis_sizes, axis_names)``.
-#   * jax.sharding.AxisType / make_mesh(axis_types=...): newer JAX only.
-#   * shard_map: ``jax.shard_map(..., check_vma=)`` on newer JAX,
-#     ``jax.experimental.shard_map.shard_map(..., check_rep=)`` on older.
-# --------------------------------------------------------------------------
-_ABSTRACT_OLD_STYLE = "shape_tuple" in inspect.signature(
-    jax.sharding.AbstractMesh.__init__).parameters
-
-
 def abstract_mesh(axis_sizes, axis_names):
-    """Version-compat ``AbstractMesh`` constructor: always call as
+    """``AbstractMesh`` for shape-only lowering:
     ``abstract_mesh((16, 16), ("data", "model"))``."""
-    if _ABSTRACT_OLD_STYLE:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
     return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axes: the model code places arrays
+    through ``constrain`` and expects the compiler to propagate the
+    rest, which Explicit axes (the ``jax.make_mesh`` default) refuse."""
     return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=(axis_type.Auto,) * len(axis_names))
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_replication=False):
-    """Version-compat shard_map (check_vma / check_rep kwarg rename)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             check_vma=check_replication)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_replication)
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
+
 
 def on_tpu() -> bool:
     """True when the default JAX backend is a TPU — the condition under

@@ -33,6 +33,7 @@ import pytest
 
 from repro.core.api import run, sweep as api_sweep
 from repro.core.spec import run_solo
+from repro.core.sweep_jax import STAT_BANDS, band_violations
 
 
 def assert_results_match(lane, solo):
@@ -88,16 +89,6 @@ def assert_sweep_equivalent(specs, seeds):
     return batched
 
 
-#: the statistical-equivalence contract surface (README "Simulation
-#: engines"): metric -> relative tolerance on the per-scenario mean
-#: (and band-widening margin).  ``preemptions`` is deliberately looser:
-#: the compiled engine kills proportionally across occupancy cells
-#: where the row engines kill newest-first, which shifts how many of a
-#: tick's kills land on busy instances without moving cost/throughput.
-STAT_BANDS = {"cost": 0.02, "accel_days": 0.02, "jobs_finished": 0.02,
-              "preemptions": 0.25, "egress_usd": 0.05}
-
-
 def assert_statistically_equivalent(specs, seeds, engine="jax",
                                     bands=None, reference="batched"):
     """Run a (specs x seeds) sweep on the statistical ``engine`` and on
@@ -115,15 +106,7 @@ def assert_statistically_equivalent(specs, seeds, engine="jax",
     ref = api_sweep(specs, seeds, engine=reference)
     gs, rs = got.summary(metrics), ref.summary(metrics)
     assert set(gs) == set(rs)
-    for scen in sorted(rs):
-        for metric, rel in bands.items():
-            a, b = rs[scen][metric], gs[scen][metric]
-            margin = rel * max(abs(a["mean"]), 1e-9)
-            assert abs(b["mean"] - a["mean"]) <= margin, \
-                (scen, metric, "mean", a, b)
-            assert a["p5"] - margin <= b["p5"] and \
-                b["p95"] <= a["p95"] + margin, \
-                (scen, metric, "band", a, b)
+    assert band_violations(rs, gs, bands) == []
     return got, ref
 
 
