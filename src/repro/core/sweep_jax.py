@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import timeline as timeline_registry
 from repro.core.spec import CampaignSpec
 from repro.core.sweep import _Lane, _THRESHOLDS, _prepare
@@ -251,17 +252,18 @@ def _poisson(u, lam):
     (statistical tier; per-tick per-group lam is O(1) in practice)."""
     from jax.scipy.special import ndtri
     K = 24
-    p = jnp.exp(-jnp.minimum(lam, 30.0))
-    cdf = p
-    kk = (u > cdf).astype(jnp.int32)
-    for j in range(1, K):
-        p = p * lam / j
-        cdf = cdf + p
-        kk = kk + (u > cdf).astype(jnp.int32)
-    z = ndtri(jnp.clip(u, 1e-7, 1.0 - 1e-7))
-    k_norm = jnp.round(lam + jnp.sqrt(jnp.maximum(lam, 0.0)) * z)
-    return jnp.where(lam > 8.0,
-                     jnp.maximum(k_norm, 0.0).astype(jnp.int32), kk)
+    with jax.named_scope("poisson"):
+        p = jnp.exp(-jnp.minimum(lam, 30.0))
+        cdf = p
+        kk = (u > cdf).astype(jnp.int32)
+        for j in range(1, K):
+            p = p * lam / j
+            cdf = cdf + p
+            kk = kk + (u > cdf).astype(jnp.int32)
+        z = ndtri(jnp.clip(u, 1e-7, 1.0 - 1e-7))
+        k_norm = jnp.round(lam + jnp.sqrt(jnp.maximum(lam, 0.0)) * z)
+        return jnp.where(lam > 8.0,
+                         jnp.maximum(k_norm, 0.0).astype(jnp.int32), kk)
 
 
 #: precision of the count-plane matmuls (counts and $ against one-hot
@@ -281,7 +283,14 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
     match, NAT drops, advance, billing, overhead, ledger thresholds,
     accumulation.  Billing charges the interval ending at this tick
     against the live set at the tick's *start*, which equals the numpy
-    engine's ``live + died - created`` counter identity."""
+    engine's ``live + died - created`` counter identity.
+
+    Each phase is a ``jax.named_scope`` (``events``, ``kill``, ``spawn``,
+    ``preempt``, ``topup``, ``match``, ``nat``, ``advance``, ``bill``,
+    ``overhead``, ``ledger``, ``accumulate``), as are ``_poisson`` and
+    the two preemption kernel call sites (``preempt_to_target``,
+    ``preempt_sampled``): they name each device op in its ``op_name``
+    metadata and change nothing else in the compiled program."""
     preempt_fn, match_fn, advance_fn, bill_fn = \
         _kernel_ops(use_pallas, consts)
 
@@ -307,14 +316,16 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
         return jnp.matmul(kb.astype(jnp.float32), M_wl, precision=_EXACT) \
             .sum(axis=1).astype(jnp.int32)
 
-    def split_cells(idle, pdead, busy, k):
+    def split_cells(idle, pdead, busy, k, scope):
         # proportional fan-out of k removals per (lane, group) across
-        # the group's occupancy cells (idle | pilot-dead | busy-at-w)
-        cells = jnp.concatenate(
-            [idle[..., None], pdead[..., None], busy], axis=2)
-        killed = preempt_fn(cells.reshape(B * G, W + 2),
-                            k.reshape(B * G)).reshape(B, G, W + 2)
-        return killed[..., 0], killed[..., 1], killed[..., 2:]
+        # the group's occupancy cells (idle | pilot-dead | busy-at-w);
+        # ``scope`` names the call site in the device trace's metadata
+        with jax.named_scope(scope):
+            cells = jnp.concatenate(
+                [idle[..., None], pdead[..., None], busy], axis=2)
+            killed = preempt_fn(cells.reshape(B * G, W + 2),
+                                k.reshape(B * G)).reshape(B, G, W + 2)
+            return killed[..., 0], killed[..., 1], killed[..., 2:]
 
     def step(c, x):
         i, seg, is_start = x
@@ -324,195 +335,209 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
         live0 = idle + pdead + busy.sum(axis=2)              # [B,G] i32
         live_g = live0
         virgin = c["virgin"]
-        if dp_staging:
-            # a CacheFlush edge marks the flushed provider's whole live
-            # population virgin: the lazy epoch reset in the row engines
-            # forces every pilot's next stage-in to miss
-            virgin = jnp.where(
-                jnp.logical_and(is_start, planes["dp_flush"][seg]),
-                live0.astype(jnp.float32), virgin)
+        with jax.named_scope("events"):
+            if dp_staging:
+                # a CacheFlush edge marks the flushed provider's whole live
+                # population virgin: the lazy epoch reset in the row engines
+                # forces every pilot's next stage-in to miss
+                virgin = jnp.where(
+                    jnp.logical_and(is_start, planes["dp_flush"][seg]),
+                    live0.astype(jnp.float32), virgin)
 
-        # 1. events: the deferred budget cap first (solo at(now) order),
-        # then this segment's net scale target (uncapped/capped pair)
-        def greedy(n):                                       # [B] -> [B,G]
-            cume = jnp.cumsum(cap_g, axis=1) - cap_g
-            return jnp.clip(n[:, None] - cume, 0, cap_g)
+            # 1. events: the deferred budget cap first (solo at(now) order),
+            # then this segment's net scale target (uncapped/capped pair)
+            def greedy(n):                                   # [B] -> [B,G]
+                cume = jnp.cumsum(cap_g, axis=1) - cap_g
+                return jnp.clip(n[:, None] - cume, 0, cap_g)
 
-        apply_cap = c["cap_pending"]
-        target_g = jnp.where(apply_cap[:, None],
-                             greedy(planes["downscale"][seg]),
-                             c["target_g"])
-        cap_tick = jnp.where(apply_cap, i, c["cap_tick"])
-        n_eff = jnp.where(c["capped"], planes["n_cap"][seg],
-                          planes["n_unc"][seg])
-        do_scale = is_start & (n_eff >= 0)
-        target_g = jnp.where(do_scale[:, None],
-                             greedy(jnp.maximum(n_eff, 0)), target_g)
+            apply_cap = c["cap_pending"]
+            target_g = jnp.where(apply_cap[:, None],
+                                 greedy(planes["downscale"][seg]),
+                                 c["target_g"])
+            cap_tick = jnp.where(apply_cap, i, c["cap_tick"])
+            n_eff = jnp.where(c["capped"], planes["n_cap"][seg],
+                              planes["n_unc"][seg])
+            do_scale = is_start & (n_eff >= 0)
+            target_g = jnp.where(do_scale[:, None],
+                                 greedy(jnp.maximum(n_eff, 0)), target_g)
 
-        # 2. kill down to target (event stops); busy kills requeue
-        excess = jnp.clip(live_g - target_g, 0, None)
-        ki, kp, kb = split_cells(idle, pdead, busy, excess)
-        idle, pdead, busy = idle - ki, pdead - kp, busy - kb
-        pre_ct = c["pre_ct"] + kb.sum(axis=(1, 2))
-        lv = c["lv"] + requeue_levels(kb)
-        live_g = live_g - ki - kp - kb.sum(axis=2)
-        if dp_staging:                     # kills hit virgins pro rata
-            virgin = virgin * live_g.astype(jnp.float32) \
-                / jnp.maximum(1.0, live0.astype(jnp.float32))
+        with jax.named_scope("kill"):
+            # 2. kill down to target (event stops); busy kills requeue
+            excess = jnp.clip(live_g - target_g, 0, None)
+            ki, kp, kb = split_cells(idle, pdead, busy, excess,
+                                     "preempt_to_target")
+            idle, pdead, busy = idle - ki, pdead - kp, busy - kb
+            pre_ct = c["pre_ct"] + kb.sum(axis=(1, 2))
+            lv = c["lv"] + requeue_levels(kb)
+            live_g = live_g - ki - kp - kb.sum(axis=2)
+            if dp_staging:                     # kills hit virgins pro rata
+                virgin = virgin * live_g.astype(jnp.float32) \
+                    / jnp.maximum(1.0, live0.astype(jnp.float32))
 
-        # 3. spawn to min(target, capacity); fresh pilots arrive idle
-        deficit = jnp.clip(jnp.minimum(target_g, cap_g) - live_g,
-                           0, None)
-        idle = idle + deficit
-        live_g = live_g + deficit
-        if dp_staging:                     # fresh pilots stage cold
-            virgin = virgin + deficit.astype(jnp.float32)
-            live_sp = live_g
+        with jax.named_scope("spawn"):
+            # 3. spawn to min(target, capacity); fresh pilots arrive idle
+            deficit = jnp.clip(jnp.minimum(target_g, cap_g) - live_g,
+                               0, None)
+            idle = idle + deficit
+            live_g = live_g + deficit
+            if dp_staging:                     # fresh pilots stage cold
+                virgin = virgin + deficit.astype(jnp.float32)
+                live_sp = live_g
 
-        # 4. preemption sampling: per-lane threefry keyed by the tick,
-        # a Poisson total per (lane, group) from the shared fleet
-        # hazard, fanned out across occupancy cells proportionally
-        subkeys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(keys, i)
-        u = jax.vmap(lambda kk: jax.random.uniform(kk, (G,)))(subkeys)
-        util = live_g.astype(jnp.float32) \
-            / jnp.maximum(1, cap_g).astype(jnp.float32)
-        hazard = pre_rate * (1.0 + (pre_scale - 1.0) * util) * dt
-        k_pre = _poisson(u, live_g.astype(jnp.float32) * hazard)
-        ki, kp, kb = split_cells(idle, pdead, busy, k_pre)
-        idle, pdead, busy = idle - ki, pdead - kp, busy - kb
-        pre_ct = pre_ct + kb.sum(axis=(1, 2))
-        lv = lv + requeue_levels(kb)
-        live_g = live_g - ki - kp - kb.sum(axis=2)
-        if dp_staging:
-            virgin = virgin * live_g.astype(jnp.float32) \
-                / jnp.maximum(1.0, live_sp.astype(jnp.float32))
+        with jax.named_scope("preempt"):
+            # 4. preemption sampling: per-lane threefry keyed by the tick,
+            # a Poisson total per (lane, group) from the shared fleet
+            # hazard, fanned out across occupancy cells proportionally
+            subkeys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(keys, i)
+            u = jax.vmap(lambda kk: jax.random.uniform(kk, (G,)))(subkeys)
+            util = live_g.astype(jnp.float32) \
+                / jnp.maximum(1, cap_g).astype(jnp.float32)
+            hazard = pre_rate * (1.0 + (pre_scale - 1.0) * util) * dt
+            k_pre = _poisson(u, live_g.astype(jnp.float32) * hazard)
+            ki, kp, kb = split_cells(idle, pdead, busy, k_pre,
+                                     "preempt_sampled")
+            idle, pdead, busy = idle - ki, pdead - kp, busy - kb
+            pre_ct = pre_ct + kb.sum(axis=(1, 2))
+            lv = lv + requeue_levels(kb)
+            live_g = live_g - ki - kp - kb.sum(axis=2)
+            if dp_staging:
+                virgin = virgin * live_g.astype(jnp.float32) \
+                    / jnp.maximum(1.0, live_sp.astype(jnp.float32))
 
-        # 5/6. top the CE queue up to the workload level
-        ring_tot = lv.sum(axis=1)
-        fresh_q = c["fresh_q"] + jnp.clip(
-            planes["minq"][seg] - (ring_tot + c["fresh_q"]), 0, None)
+        with jax.named_scope("topup"):
+            # 5/6. top the CE queue up to the workload level
+            ring_tot = lv.sum(axis=1)
+            fresh_q = c["fresh_q"] + jnp.clip(
+                planes["minq"][seg] - (ring_tot + c["fresh_q"]), 0, None)
 
-        # 7. match k = min(idle, queued) jobs: the requeued ring drains
-        # first (highest checkpoint level first), then fresh jobs; the
-        # matcher splits k across groups by idle-pilot counts and the
-        # joint (group x queue-slice) pairing is the overlap of the two
-        # cumulative partitions of [0, k).  Origin outages remove the
-        # gated groups' idle pilots from the matcher's input (they stay
-        # idle and billed, exactly like the row engines' skip).
-        if dp_gating:
-            idle_m = idle * planes["origin_up"][seg]
-        else:
-            idle_m = idle
-        idle_tot = idle_m.sum(axis=1)
-        k = jnp.minimum(idle_tot, ring_tot + fresh_q)
-        k = jnp.where(planes["outage"][seg], 0, k)
-        take_g = match_fn(idle_m, k)                         # [B,G]
-        avail = jnp.concatenate([lv[:, ::-1], fresh_q[:, None]], axis=1)
-        cumq = jnp.cumsum(avail, axis=1)
-        take_j = jnp.clip(k[:, None] - (cumq - avail), 0, avail)
-        cA = jnp.cumsum(take_g, axis=1)
-        cB = jnp.cumsum(take_j, axis=1)
-        lo = jnp.maximum((cA - take_g)[:, :, None],
-                         (cB - take_j)[:, None, :])
-        hi = jnp.minimum(cA[:, :, None], cB[:, None, :])
-        joint = jnp.clip(hi - lo, 0, None).astype(jnp.float32)
-        if dp_staging:
-            # stage-in as a count-axis front extension: a matched job
-            # enters at S_max + w0 - S and reaches its old entry step
-            # after S staging ticks.  The hit/miss split is the
-            # deterministic per-(lane, group) fractional accumulator —
-            # the mixture analogue of the row engines' per-pilot
-            # rotation (long-run hit frequency exactly r, no RNG).
-            # Each virgin (freshly spawned or freshly flushed) pilot
-            # restarts its rotation at k=0, losing the fractional hit
-            # credit a mid-rotation pilot carries — expected deficit
-            # E[frac(n*r)] per reset (dp_loss_g) — charged the tick the
-            # virgin first matches.
-            take_f = take_g.astype(jnp.float32)
-            first_f = jnp.minimum(take_f, virgin)
-            virgin = virgin - first_f
-            acc = c["hit_acc"] + take_f * consts["dp_r_g"][None, :] \
-                - first_f * consts["dp_loss_g"][None, :]
-            th_f = jnp.clip(jnp.floor(acc), 0.0, take_f)
-            hit_acc = acc - th_f
-            cumj = jnp.cumsum(joint, axis=2)
-            hit_j = jnp.clip(th_f[:, :, None] - (cumj - joint),
-                             0.0, joint)
-            miss_j = joint - hit_j
-            inc = (hit_j[..., None] * consts["E_hit"]).sum(axis=2) \
-                + (miss_j[..., None] * planes["E_miss"][seg]).sum(axis=2)
-            busy = busy + inc.astype(jnp.int32)
-            has = consts["dp_has_g"][None, :]
-            miss_f = (take_f - th_f) * has
-            hits = c["hits"] + (th_f * has).sum(axis=1)
-            misses = c["misses"] + miss_f.sum(axis=1)
-            stage_t = c["stage_t"] \
-                + (th_f * consts["S_hit_g"][None, :]
-                   + (take_f - th_f)
-                   * planes["S_miss"][seg].astype(jnp.float32)) \
-                .sum(axis=1)
-            # cache-miss egress: usd/miss is precomputed (gb * price);
-            # charged the tick the job matched, next to the GPU hours
-            eg_g = (take_f - th_f) * consts["dp_usd_miss_g"][None, :]
-            egress_g = c["egress_g"] + eg_g
-        else:
-            busy = busy + jnp.matmul(joint, M_jw, precision=_EXACT) \
-                .astype(jnp.int32)
-            hit_acc, hits, misses = c["hit_acc"], c["hits"], c["misses"]
-            stage_t, egress_g = c["stage_t"], c["egress_g"]
-            eg_g = jnp.zeros_like(egress_g)
-        idle = idle - take_g
-        lv = lv - take_j[:, :L][:, ::-1]
-        fresh_q = fresh_q - take_j[:, L]
+        with jax.named_scope("match"):
+            # 7. match k = min(idle, queued) jobs: the requeued ring drains
+            # first (highest checkpoint level first), then fresh jobs; the
+            # matcher splits k across groups by idle-pilot counts and the
+            # joint (group x queue-slice) pairing is the overlap of the two
+            # cumulative partitions of [0, k).  Origin outages remove the
+            # gated groups' idle pilots from the matcher's input (they stay
+            # idle and billed, exactly like the row engines' skip).
+            if dp_gating:
+                idle_m = idle * planes["origin_up"][seg]
+            else:
+                idle_m = idle
+            idle_tot = idle_m.sum(axis=1)
+            k = jnp.minimum(idle_tot, ring_tot + fresh_q)
+            k = jnp.where(planes["outage"][seg], 0, k)
+            take_g = match_fn(idle_m, k)                         # [B,G]
+            avail = jnp.concatenate([lv[:, ::-1], fresh_q[:, None]], axis=1)
+            cumq = jnp.cumsum(avail, axis=1)
+            take_j = jnp.clip(k[:, None] - (cumq - avail), 0, avail)
+            cA = jnp.cumsum(take_g, axis=1)
+            cB = jnp.cumsum(take_j, axis=1)
+            lo = jnp.maximum((cA - take_g)[:, :, None],
+                             (cB - take_j)[:, None, :])
+            hi = jnp.minimum(cA[:, :, None], cB[:, None, :])
+            joint = jnp.clip(hi - lo, 0, None).astype(jnp.float32)
+            if dp_staging:
+                # stage-in as a count-axis front extension: a matched job
+                # enters at S_max + w0 - S and reaches its old entry step
+                # after S staging ticks.  The hit/miss split is the
+                # deterministic per-(lane, group) fractional accumulator —
+                # the mixture analogue of the row engines' per-pilot
+                # rotation (long-run hit frequency exactly r, no RNG).
+                # Each virgin (freshly spawned or freshly flushed) pilot
+                # restarts its rotation at k=0, losing the fractional hit
+                # credit a mid-rotation pilot carries — expected deficit
+                # E[frac(n*r)] per reset (dp_loss_g) — charged the tick the
+                # virgin first matches.
+                take_f = take_g.astype(jnp.float32)
+                first_f = jnp.minimum(take_f, virgin)
+                virgin = virgin - first_f
+                acc = c["hit_acc"] + take_f * consts["dp_r_g"][None, :] \
+                    - first_f * consts["dp_loss_g"][None, :]
+                th_f = jnp.clip(jnp.floor(acc), 0.0, take_f)
+                hit_acc = acc - th_f
+                cumj = jnp.cumsum(joint, axis=2)
+                hit_j = jnp.clip(th_f[:, :, None] - (cumj - joint),
+                                 0.0, joint)
+                miss_j = joint - hit_j
+                inc = (hit_j[..., None] * consts["E_hit"]).sum(axis=2) \
+                    + (miss_j[..., None] * planes["E_miss"][seg]).sum(axis=2)
+                busy = busy + inc.astype(jnp.int32)
+                has = consts["dp_has_g"][None, :]
+                miss_f = (take_f - th_f) * has
+                hits = c["hits"] + (th_f * has).sum(axis=1)
+                misses = c["misses"] + miss_f.sum(axis=1)
+                stage_t = c["stage_t"] \
+                    + (th_f * consts["S_hit_g"][None, :]
+                       + (take_f - th_f)
+                       * planes["S_miss"][seg].astype(jnp.float32)) \
+                    .sum(axis=1)
+                # cache-miss egress: usd/miss is precomputed (gb * price);
+                # charged the tick the job matched, next to the GPU hours
+                eg_g = (take_f - th_f) * consts["dp_usd_miss_g"][None, :]
+                egress_g = c["egress_g"] + eg_g
+            else:
+                busy = busy + jnp.matmul(joint, M_jw, precision=_EXACT) \
+                    .astype(jnp.int32)
+                hit_acc, hits, misses = c["hit_acc"], c["hits"], c["misses"]
+                stage_t, egress_g = c["stage_t"], c["egress_g"]
+                eg_g = jnp.zeros_like(egress_g)
+            idle = idle - take_g
+            lv = lv - take_j[:, :L][:, ::-1]
+            fresh_q = fresh_q - take_j[:, L]
 
-        # 7.5 NAT drops: every busy pilot in a disconnected group
-        # requeues its job (instance stays alive and billed, pilot dead)
-        nat_ct = c["nat_ct"]
-        if nat_any:
-            drop = busy * nat_g[:, :, None]
-            cnt = drop.sum(axis=(1, 2))
-            lv = lv + requeue_levels(drop)
-            nat_ct = nat_ct + cnt
-            pre_ct = pre_ct + cnt
-            busy = busy - drop
-            pdead = pdead + drop.sum(axis=2)
+        with jax.named_scope("nat"):
+            # 7.5 NAT drops: every busy pilot in a disconnected group
+            # requeues its job (instance stays alive and billed, pilot dead)
+            nat_ct = c["nat_ct"]
+            if nat_any:
+                drop = busy * nat_g[:, :, None]
+                cnt = drop.sum(axis=(1, 2))
+                lv = lv + requeue_levels(drop)
+                nat_ct = nat_ct + cnt
+                pre_ct = pre_ct + cnt
+                busy = busy - drop
+                pdead = pdead + drop.sum(axis=2)
 
-        # 8. advance progress one dt step; finishes release the pilot
-        adv, fin = advance_fn(busy.reshape(B * G, W), finmask_rg)
-        busy = adv.reshape(B, G, W)
-        fin_g = fin.reshape(B, G)
-        fin_ct = c["fin_ct"] + fin_g.sum(axis=1)
-        idle = idle + fin_g
+        with jax.named_scope("advance"):
+            # 8. advance progress one dt step; finishes release the pilot
+            adv, fin = advance_fn(busy.reshape(B * G, W), finmask_rg)
+            busy = adv.reshape(B, G, W)
+            fin_g = fin.reshape(B, G)
+            fin_ct = c["fin_ct"] + fin_g.sum(axis=1)
+            idle = idle + fin_g
 
-        # 9. bill the interval ending at this tick against the tick's
-        # starting live set, at post-event rates (numpy counter identity)
-        dh = jnp.where(i > 0, dt, 0.0)
-        spent_d, prov_d = bill_fn(live0, rate_g * dh)
-        spent = c["spent"] + spent_d + eg_g.sum(axis=1)
-        by_prov = c["by_prov"] + prov_d
+        with jax.named_scope("bill"):
+            # 9. bill the interval ending at this tick against the tick's
+            # starting live set, at post-event rates (numpy counter identity)
+            dh = jnp.where(i > 0, dt, 0.0)
+            spent_d, prov_d = bill_fn(live0, rate_g * dh)
+            spent = c["spent"] + spent_d + eg_g.sum(axis=1)
+            by_prov = c["by_prov"] + prov_d
 
-        # 10. flat infra overhead
-        oh = overhead * dt / 24.0
-        chg = oh > 0
-        spent = spent + jnp.where(chg, oh, 0.0)
-        infra = c["infra"] + jnp.where(chg, oh, 0.0)
+        with jax.named_scope("overhead"):
+            # 10. flat infra overhead
+            oh = overhead * dt / 24.0
+            chg = oh > 0
+            spent = spent + jnp.where(chg, oh, 0.0)
+            infra = c["infra"] + jnp.where(chg, oh, 0.0)
 
-        # 11. ledger alert thresholds -> budget-floor tripwire (the cap
-        # itself applies at the next tick's event phase)
-        frac = jnp.maximum(0.0, budget - spent) / budget
-        cross = (frac[:, None] <= thresholds[None, :]) & ~c["fired"]
-        newly = cross.any(axis=1)
-        fired = c["fired"] | cross
-        trigger = newly & (frac <= planes["floor"][seg]) & ~c["capped"]
-        capped = c["capped"] | trigger
+        with jax.named_scope("ledger"):
+            # 11. ledger alert thresholds -> budget-floor tripwire (the cap
+            # itself applies at the next tick's event phase)
+            frac = jnp.maximum(0.0, budget - spent) / budget
+            cross = (frac[:, None] <= thresholds[None, :]) & ~c["fired"]
+            newly = cross.any(axis=1)
+            fired = c["fired"] | cross
+            trigger = newly & (frac <= planes["floor"][seg]) & ~c["capped"]
+            capped = c["capped"] | trigger
 
-        # 12. accumulate GPU-time totals at end-of-tick occupancy
-        busy_g = busy.sum(axis=2).astype(jnp.float32)
-        live_end = (idle + pdead).astype(jnp.float32) + busy_g
-        accel = c["accel"] + live_end.sum(axis=1) * dt
-        busy_h = c["busy_h"] + busy_g.sum(axis=1) * dt
-        busy_prov = c["busy_prov"] \
-            + jnp.matmul(busy_g, prov_onehot, precision=_EXACT) * dt
+        with jax.named_scope("accumulate"):
+            # 12. accumulate GPU-time totals at end-of-tick occupancy
+            busy_g = busy.sum(axis=2).astype(jnp.float32)
+            live_end = (idle + pdead).astype(jnp.float32) + busy_g
+            accel = c["accel"] + live_end.sum(axis=1) * dt
+            busy_h = c["busy_h"] + busy_g.sum(axis=1) * dt
+            busy_prov = c["busy_prov"] \
+                + jnp.matmul(busy_g, prov_onehot, precision=_EXACT) * dt
 
         return {"idle": idle, "pdead": pdead, "busy": busy,
                 "target_g": target_g, "lv": lv, "fresh_q": fresh_q,
@@ -834,9 +859,21 @@ class JaxSweepEngine:
         return _scan_campaigns.lower(*args, **kw)
 
     def run(self) -> "JaxSweepEngine":
-        args, kw = self._scan_call()
-        out = _scan_campaigns(*args, **kw)
-        self.out = {k: np.asarray(v) for k, v in out.items()}
+        """Stages the arguments, dispatches the scan, waits for the
+        device and copies the outputs back: one ``obs`` span each."""
+        with obs.span("engine.put"):
+            args, kw = self._scan_call()
+            staged = jax.tree.leaves(args)
+            obs.count("h2d_bytes", sum(a.nbytes for a in staged))
+            obs.count("h2d_arrays", len(staged))
+        with obs.span("engine.launch"):
+            out = _scan_campaigns(*args, **kw)
+        with obs.span("engine.wait"):
+            out = jax.block_until_ready(out)
+        with obs.span("engine.fetch"):
+            self.out = {k: np.asarray(v) for k, v in out.items()}
+            obs.count("d2h_bytes", sum(v.nbytes for v in self.out.values()))
+            obs.count("d2h_arrays", len(self.out))
         return self
 
     # -- per-lane provenance + results ------------------------------------
@@ -944,17 +981,27 @@ def run_jax_detailed(lane_specs: Sequence[Tuple[CampaignSpec, int]],
     ``(results, events_fired, None)`` in input order (the trace slot is
     always None — ``collect="trace"`` is a bit-identity surface the
     statistical engine does not implement)."""
-    prepared = [_prepare(sc, seed) for sc, seed in lane_specs]
-    batches: Dict[tuple, List[int]] = {}
-    for i, (key, _lane) in enumerate(prepared):
-        batches.setdefault(key, []).append(i)
-    out: List[Optional[tuple]] = [None] * len(prepared)
-    for idxs in batches.values():
-        eng = JaxSweepEngine([prepared[i][1] for i in idxs],
-                             use_pallas=use_pallas).run()
-        for j, i in enumerate(idxs):
-            out[i] = (eng.lane_results(j), eng.lane_events(j), None)
-    return out
+    with obs.call("engine.call"):
+        with obs.span("engine.prepare"):
+            prepared = [_prepare(sc, seed) for sc, seed in lane_specs]
+        batches: Dict[tuple, List[int]] = {}
+        for i, (key, _lane) in enumerate(prepared):
+            batches.setdefault(key, []).append(i)
+        out: List[Optional[tuple]] = [None] * len(prepared)
+        for idxs in batches.values():
+            with obs.span("engine.bake"):
+                eng = JaxSweepEngine([prepared[i][1] for i in idxs],
+                                     use_pallas=use_pallas)
+            with obs.span("engine.scan"):
+                eng.run()
+            lanes = range(len(idxs))
+            with obs.span("engine.results"):
+                results = [eng.lane_results(j) for j in lanes]
+            with obs.span("engine.events"):
+                events = [eng.lane_events(j) for j in lanes]
+            for i, res, evs in zip(idxs, results, events):
+                out[i] = (res, evs, None)
+        return out
 
 
 def run_jax(lane_specs: Sequence[Tuple[CampaignSpec, int]],

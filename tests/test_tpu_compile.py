@@ -12,6 +12,7 @@ is imported: only one process at a time may load the TPU library, and a
 test worker that merely imports this file must not take it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,4 +112,15 @@ def test_scan_compiles_with_native_kernels(one_chip, monkeypatch, spec):
         nat_any=eng.nat_any, use_pallas=True,
         dp_gating=eng.dp_active, dp_staging=eng.dp_staging).compile()
     # four tick kernels, each a custom call (preempt runs twice a tick)
-    assert compiled.as_text().count("tpu_custom_call") >= 4
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4
+    # the kernels keep their names (the benchmark finds them by these),
+    # and each preemption call site carries its scope in the metadata
+    kernels = re.findall(r"%(campaign_\w+?)\.\d+ = .*?op_name=\"([^\"]*)\"",
+                         text)
+    assert {k for k, _op in kernels} == {
+        "campaign_preempt", "campaign_match", "campaign_advance",
+        "campaign_bill"}
+    sites = {op.split("/preempt_")[1].split("/")[0]
+             for k, op in kernels if k == "campaign_preempt"}
+    assert sites == {"to_target", "sampled"}
