@@ -23,7 +23,10 @@ deterministically.
 TPU adaptation notes:
   * the grid tiles the row axis only (``block_r`` rows per program); a
     program sees each row's full cell axis, so every op is one VPU pass
-    with no cross-program reductions,
+    with no cross-program reductions.  A grid step has a fixed cost, so
+    the ops.py wrappers size ``block_r`` from the rows
+    (``ops.tick_row_block``): the fewest equal blocks of at most
+    ``ops.TICK_BLOCK_ROWS`` rows (a VMEM budget), each a multiple of 8,
   * counts travel as int32 (Pallas TPU has no first-class bool tiles)
     and the allocator's scale factor rides in f32 — cumulative counts
     stay far below 2**24, so the f32 floors are exact,

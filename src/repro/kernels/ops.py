@@ -113,9 +113,29 @@ def moe_gmm(x, w, *, block_c=128, block_f=128, block_k=128, interpret=None):
 
 # -- campaign-sweep tick ops (core/sweep_jax.py) ---------------------------
 # Same contract as the model kernels above: the wrapper owns layout
-# padding (cell axis to a VPU lane multiple, row axis to the row-block)
+# padding (cell axis to a VPU lane multiple, row axis to the row block)
 # and the interpret-mode policy; kernels/ref.py holds the jnp oracles
 # (and the allocator scale both sides share).
+#
+# A grid step costs a fixed ~0.5 us on a v5e whatever its rows, so each
+# wrapper takes the fewest row blocks that fit a VMEM budget.  A block
+# row costs 128 lanes x 4 bytes in every (block, *) plane, the (block, 1)
+# columns included, and 16 such planes bound every tick kernel: at most
+# 4 operand blocks, double-buffered, plus the body's temporaries (the
+# allocator's iota, roll, inc, inc_f and exc_f).
+TICK_VMEM_BUDGET = 8 << 20           # half of v5e's 16 MiB scoped VMEM
+TICK_ROW_BYTES = 16 * 128 * 4
+TICK_BLOCK_ROWS = TICK_VMEM_BUDGET // TICK_ROW_BYTES        # 1,024
+
+
+def tick_row_block(rows, cap=TICK_BLOCK_ROWS):
+    """Row block for ``rows`` rows: the fewest equal blocks of at most
+    ``cap`` rows, each rounded up to a multiple of 8 sublanes, so the
+    rows pad to ``ceil(rows / block)`` blocks with under 8 rows of
+    padding a block."""
+    n = -(-rows // cap)
+    return (-(-rows // n) + 7) // 8 * 8
+
 
 def _pad2(x, block_r, c_mult=128):
     x, _ = _pad_to(x, 0, block_r)
@@ -123,14 +143,14 @@ def _pad2(x, block_r, c_mult=128):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def campaign_preempt(counts, k, *, block_r=8, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def campaign_preempt(counts, k, *, interpret=None):
     """Preemption fan-out: counts (R,C) i32 occupancy cells per
     (lane, group) row, k (R,) i32 sampled preemption counts ->
     killed (R,C) i32 (proportional systematic split)."""
     interpret = default_interpret(interpret)
     R, C = counts.shape
-    br = min(block_r, R)
+    br = tick_row_block(R)
     counts = counts.astype(jnp.int32)
     sp = _pad_to(campaign_alloc_scale(counts, k)[:, None], 0, br)[0]
     killed = campaign_preempt_kernel(_pad2(counts, br), sp,
@@ -138,13 +158,13 @@ def campaign_preempt(counts, k, *, block_r=8, interpret=None):
     return killed[:R, :C]
 
 
-@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def campaign_match(idle, k, *, block_r=8, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def campaign_match(idle, k, *, interpret=None):
     """Queue->pilot matcher core: idle (B,G) i32 idle-pilot counts,
     k (B,) i32 matched jobs per lane -> take (B,G) i32."""
     interpret = default_interpret(interpret)
     B, G = idle.shape
-    br = min(block_r, B)
+    br = tick_row_block(B)
     idle = idle.astype(jnp.int32)
     sp = _pad_to(campaign_alloc_scale(idle, k)[:, None], 0, br)[0]
     take = campaign_match_kernel(_pad2(idle, br), sp,
@@ -152,13 +172,13 @@ def campaign_match(idle, k, *, block_r=8, interpret=None):
     return take[:B, :G]
 
 
-@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def campaign_advance(busy, fin_mask, *, block_r=8, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def campaign_advance(busy, fin_mask, *, interpret=None):
     """Pilot progress sync: busy (R,W) i32 job counts by progress step,
     fin_mask (R,W) bool -> (advanced (R,W) i32, finished (R,) i32)."""
     interpret = default_interpret(interpret)
     R, W = busy.shape
-    br = min(block_r, R)
+    br = tick_row_block(R)
     adv, fin = campaign_advance_kernel(
         _pad2(busy.astype(jnp.int32), br),
         _pad2(fin_mask.astype(jnp.int32), br),
@@ -166,15 +186,15 @@ def campaign_advance(busy, fin_mask, *, block_r=8, interpret=None):
     return adv[:R, :W], fin[:R, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def campaign_bill(live, rate, prov_onehot, *, block_r=8, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def campaign_bill(live, rate, prov_onehot, *, interpret=None):
     """Billing/ledger reduction: live (B,G) i32 instance counts,
     rate (B,G) f32, prov_onehot (G,P) f32 -> (spent (B,) f32,
     by_provider (B,P) f32)."""
     interpret = default_interpret(interpret)
     B, G = live.shape
     P = prov_onehot.shape[1]
-    br = min(block_r, B)
+    br = tick_row_block(B)
     oh = _pad_to(_pad_to(prov_onehot.astype(jnp.float32), 0, 128)[0],
                  1, 128)[0]
     spent, by_prov = campaign_bill_kernel(

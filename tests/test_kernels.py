@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps vs the ref.py pure-jnp oracles
 (interpret=True on CPU; assignment requirement)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -120,8 +122,24 @@ def _counts(key, shape, hi=30):
     return jax.random.randint(key, shape, 0, hi, dtype=jnp.int32)
 
 
-@pytest.mark.parametrize("R,C", [(8, 5), (20, 10), (3, 16), (64, 7)])
-def test_campaign_preempt(R, C):
+def _tick(fn, cap, monkeypatch):
+    """The wrapper as the engine calls it, or, given ``cap``, run afresh
+    with its row block chosen under that cap (several blocks, the last
+    one ragged, at a test's small R)."""
+    if cap is None:
+        return fn
+    monkeypatch.setattr(ops, "tick_row_block",
+                        functools.partial(ops.tick_row_block, cap=cap))
+    return fn.__wrapped__
+
+
+# the row-block rule's edges: one row, R not a multiple of 8, three
+# blocks under a cap of 16 (37 rows pad to 48), and the paper's shapes
+# at B = 32 (G = 10, W = 16)
+@pytest.mark.parametrize("R,C,cap", [
+    (8, 5, None), (20, 10, None), (3, 16, None), (64, 7, None),
+    (1, 18, None), (13, 18, None), (37, 18, 16), (320, 18, None)])
+def test_campaign_preempt(R, C, cap, monkeypatch):
     ks = jax.random.split(KEY, 2)
     counts = _counts(ks[0], (R, C))
     tot = counts.sum(-1)
@@ -131,7 +149,8 @@ def test_campaign_preempt(R, C):
                          tot[2:3] + 7,
                          jax.random.randint(ks[1], (R - 3,), 0, 40)
                          .astype(jnp.int32)]) if R >= 3 else tot
-    killed = ops.campaign_preempt(counts, k, interpret=True)
+    killed = _tick(ops.campaign_preempt, cap, monkeypatch)(
+        counts, k, interpret=True)
     killed_ref = ref.campaign_preempt_ref(counts, k)
     np.testing.assert_array_equal(np.asarray(killed),
                                   np.asarray(killed_ref))
@@ -142,23 +161,29 @@ def test_campaign_preempt(R, C):
         kil.sum(-1), np.minimum(np.asarray(k), cnt.sum(-1)))
 
 
-@pytest.mark.parametrize("B,G", [(4, 3), (16, 10), (9, 12)])
-def test_campaign_match(B, G):
+@pytest.mark.parametrize("B,G,cap", [
+    (4, 3, None), (16, 10, None), (9, 12, None),
+    (1, 10, None), (13, 10, None), (37, 10, 16), (32, 10, None)])
+def test_campaign_match(B, G, cap, monkeypatch):
     ks = jax.random.split(KEY, 2)
     idle = _counts(ks[0], (B, G))
     k = jax.random.randint(ks[1], (B,), 0, 60).astype(jnp.int32)
-    take = ops.campaign_match(idle, k, interpret=True)
+    take = _tick(ops.campaign_match, cap, monkeypatch)(idle, k,
+                                                        interpret=True)
     take_ref = ref.campaign_match_ref(idle, k)
     np.testing.assert_array_equal(np.asarray(take), np.asarray(take_ref))
 
 
-@pytest.mark.parametrize("R,W", [(8, 16), (20, 16), (5, 9)])
-def test_campaign_advance(R, W):
+@pytest.mark.parametrize("R,W,cap", [
+    (8, 16, None), (20, 16, None), (5, 9, None),
+    (1, 16, None), (13, 16, None), (37, 16, 16), (320, 16, None)])
+def test_campaign_advance(R, W, cap, monkeypatch):
     ks = jax.random.split(KEY, 2)
     busy = _counts(ks[0], (R, W))
     wfin1 = jax.random.randint(ks[1], (R, 1), 1, W)
     fin_mask = jnp.arange(W)[None, :] >= wfin1     # suffix, like finmask
-    adv, fin = ops.campaign_advance(busy, fin_mask, interpret=True)
+    adv, fin = _tick(ops.campaign_advance, cap, monkeypatch)(
+        busy, fin_mask, interpret=True)
     adv_ref, fin_ref = ref.campaign_advance_ref(busy, fin_mask)
     np.testing.assert_array_equal(np.asarray(adv), np.asarray(adv_ref))
     np.testing.assert_array_equal(np.asarray(fin), np.asarray(fin_ref))
@@ -172,15 +197,18 @@ def test_campaign_advance(R, W):
         np.asarray(busy).sum(-1))
 
 
-@pytest.mark.parametrize("B,G,P", [(4, 3, 2), (16, 10, 3), (7, 12, 5)])
-def test_campaign_bill(B, G, P):
+@pytest.mark.parametrize("B,G,P,cap", [
+    (4, 3, 2, None), (16, 10, 3, None), (7, 12, 5, None),
+    (1, 10, 3, None), (13, 10, 3, None), (37, 10, 3, 16),
+    (32, 10, 3, None)])
+def test_campaign_bill(B, G, P, cap, monkeypatch):
     ks = jax.random.split(KEY, 3)
     live = _counts(ks[0], (B, G))
     rate = jax.random.uniform(ks[1], (B, G), minval=0.1, maxval=5.0)
     prov = jax.random.randint(ks[2], (G,), 0, P)
     onehot = jax.nn.one_hot(prov, P, dtype=jnp.float32)
-    spent, by_prov = ops.campaign_bill(live, rate, onehot,
-                                       interpret=True)
+    spent, by_prov = _tick(ops.campaign_bill, cap, monkeypatch)(
+        live, rate, onehot, interpret=True)
     spent_ref, by_prov_ref = ref.campaign_bill_ref(live, rate, onehot)
     np.testing.assert_allclose(np.asarray(spent), np.asarray(spent_ref),
                                rtol=1e-6, atol=1e-6)
@@ -189,3 +217,20 @@ def test_campaign_bill(B, G, P):
                                rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(by_prov).sum(-1),
                                np.asarray(spent), rtol=1e-6, atol=1e-6)
+
+
+# rows -> grid steps: the match and bill rows at B = 32 and 1020, the
+# preempt and advance rows there (G = 10: 320, 10,200) and at B = 4080,
+# the edges around the cap, and a small cap whose last block is ragged
+@pytest.mark.parametrize("R,cap,steps", [
+    (1, None, 1), (7, None, 1), (32, None, 1), (320, None, 1),
+    (1020, None, 1), (1024, None, 1), (1025, None, 2), (10200, None, 10),
+    (40800, None, 40), (37, 16, 3)])
+def test_tick_row_block(R, cap, steps):
+    cap = cap or ops.TICK_BLOCK_ROWS
+    block = ops.tick_row_block(R, cap)
+    n = -(-R // block)
+    assert n == steps == -(-R // cap)      # the fewest blocks under cap
+    assert block % 8 == 0
+    assert R <= n * block < R + 8 * n      # under 8 pad rows a step
+    assert block * ops.TICK_ROW_BYTES <= ops.TICK_VMEM_BUDGET

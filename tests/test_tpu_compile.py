@@ -68,7 +68,7 @@ def _struct(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
-def _kernel_args(eng, name):
+def _kernel_args(eng, name, B=B):
     i32, f32 = jnp.int32, jnp.float32
     G, W, P = eng.G, eng.W, eng.Pn
     return {
@@ -79,12 +79,16 @@ def _kernel_args(eng, name):
     }[name]
 
 
+# B = 1020 is the planning grid's width, the benchmark's widest: its
+# 10,200 preempt and advance rows take ten row blocks
+@pytest.mark.parametrize("lanes", [B, 1020])
 @pytest.mark.parametrize("name", ["campaign_preempt", "campaign_match",
                                   "campaign_advance", "campaign_bill"])
-def test_campaign_kernel_compiles_natively(one_chip, paper_engine, name):
+def test_campaign_kernel_compiles_natively(one_chip, paper_engine, name,
+                                           lanes):
     fn = getattr(ops, name)
     args = [_struct(one_chip, shape, dtype)
-            for shape, dtype in _kernel_args(paper_engine, name)]
+            for shape, dtype in _kernel_args(paper_engine, name, lanes)]
     compiled = jax.jit(
         lambda *a: fn(*a, interpret=False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
