@@ -591,6 +591,29 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
 
 # -- batch construction ----------------------------------------------------
 
+def _distinct(specs: Sequence) -> Tuple[List[int], List[int]]:
+    """Each spec's row among the distinct specs, and the position of
+    each row's first spec.  Equal specs share a row whether or not they
+    are one object; the identity check first spares hashing a spec per
+    lane.  An unhashable spec gets a row of its own."""
+    rows: Dict[object, int] = {}
+    row_of_id: Dict[int, int] = {}
+    u_of: List[int] = []
+    firsts: List[int] = []
+    for i, sc in enumerate(specs):
+        u = row_of_id.get(id(sc))
+        if u is None:
+            try:
+                u = rows.setdefault(sc, len(firsts))
+            except TypeError:
+                u = len(firsts)
+            if u == len(firsts):
+                firsts.append(i)
+            row_of_id[id(sc)] = u
+        u_of.append(u)
+    return u_of, firsts
+
+
 class JaxSweepEngine:
     """One lock-step batch of lanes compiled to a single scan (the JAX
     analogue of ``BatchedFleetEngine`` — same batching key, so the two
@@ -640,18 +663,34 @@ class JaxSweepEngine:
         N = len(times)
         self.N = N
 
-        # compile timelines; segments = union of all lanes' fire ticks
-        self._evs: List[List[tuple]] = []
-        self._fts: List[np.ndarray] = []
+        # the bake is a function of the spec alone (pairs come from the
+        # spec): it runs once per distinct spec, as row u of (U, ...)
+        # arrays, and one gather over u_of_b gives each lane its row
+        u_of, firsts = _distinct([ln.spec for ln in self.lanes])
+        reps = [self.lanes[i] for i in firsts]
+        U = len(reps)
+        u_of_b = np.array(u_of, np.int64)
+
+        def at_lanes(a: np.ndarray, axis: int) -> np.ndarray:
+            return a if U == B else np.take(a, u_of_b, axis=axis)
+
+        obs.count("bake_lanes", B)
+        obs.count("bake_specs", U)
+
+        # compile timelines; segments = union of all specs' fire ticks
+        evs_u: List[List[tuple]] = []
+        fts_u: List[np.ndarray] = []
         seg_set = {0}
-        for ln in self.lanes:
+        for ln in reps:
             evs = timeline_registry.compile_timeline(ln.spec.timeline)
             ft = np.searchsorted(self.tick_times,
                                  np.array([e[0] for e in evs]), "left") \
                 if evs else np.zeros(0, np.int64)
-            self._evs.append(evs)
-            self._fts.append(ft)
+            evs_u.append(evs)
+            fts_u.append(ft)
             seg_set.update(int(t) for t in ft if t < N)
+        self._evs: List[List[tuple]] = [evs_u[u] for u in u_of]
+        self._fts: List[np.ndarray] = [fts_u[u] for u in u_of]
         seg_ticks = np.array(sorted(seg_set), np.int64)
         n_seg = len(seg_ticks)
         seg_of_tick = (np.searchsorted(seg_ticks, np.arange(N), "right")
@@ -659,24 +698,24 @@ class JaxSweepEngine:
         is_seg_start = np.zeros(N, bool)
         is_seg_start[seg_ticks] = True
 
-        # drive the EngineOps adapter through every lane's events, once
+        # drive the EngineOps adapter through every spec's events, once
         # uncapped and once capped, snapshotting planes per segment
-        rate = np.zeros((n_seg, B, G), np.float32)
-        cap = np.zeros((n_seg, B, G), np.int32)
-        outage = np.zeros((n_seg, B), bool)
-        floor = np.zeros((n_seg, B), np.float32)
-        downscale = np.zeros((n_seg, B), np.int32)
-        minq = np.zeros((n_seg, B), np.int32)
-        n_unc = np.full((n_seg, B), -1, np.int32)
-        n_cap = np.full((n_seg, B), -1, np.int32)
-        origin_up = np.ones((n_seg, B, G), bool)
-        dp_degrade_sbg = np.ones((n_seg, B, G))
-        dp_flush_sbg = np.zeros((n_seg, B, G), bool)
-        for b, ln in enumerate(self.lanes):
+        rate = np.zeros((n_seg, U, G), np.float32)
+        cap = np.zeros((n_seg, U, G), np.int32)
+        outage = np.zeros((n_seg, U), bool)
+        floor = np.zeros((n_seg, U), np.float32)
+        downscale = np.zeros((n_seg, U), np.int32)
+        minq = np.zeros((n_seg, U), np.int32)
+        n_unc = np.full((n_seg, U), -1, np.int32)
+        n_cap = np.full((n_seg, U), -1, np.int32)
+        origin_up = np.ones((n_seg, U, G), bool)
+        dp_degrade_sbg = np.ones((n_seg, U, G))
+        dp_flush_sbg = np.zeros((n_seg, U, G), bool)
+        for u, ln in enumerate(reps):
             ops_u = JaxLaneOps(ln.spec, ln.pairs, budget_capped=False)
             ops_c = JaxLaneOps(ln.spec, ln.pairs, budget_capped=True)
             by_tick: Dict[int, list] = {}
-            for (t, kind, arg), ft in zip(self._evs[b], self._fts[b]):
+            for (t, kind, arg), ft in zip(evs_u[u], fts_u[u]):
                 if ft < N:
                     by_tick.setdefault(int(ft), []).append((kind, arg))
             for s, st in enumerate(seg_ticks):
@@ -686,45 +725,45 @@ class JaxSweepEngine:
                 for kind, arg in by_tick.get(int(st), []):
                     timeline_registry.apply_op(ops_u, kind, arg, 0.0)
                     timeline_registry.apply_op(ops_c, kind, arg, 0.0)
-                rate[s, b] = ops_u.rate_h()
-                cap[s, b] = ops_u.cap
-                outage[s, b] = ops_u.outage
-                floor[s, b] = ops_u.floor_fraction
-                downscale[s, b] = ops_u.downscale_target
-                minq[s, b] = ops_u.min_queue_eff
-                origin_up[s, b] = ops_u.origin_up
-                dp_degrade_sbg[s, b] = ops_u.dp_degrade
-                dp_flush_sbg[s, b] = ops_u.flush_edge
+                rate[s, u] = ops_u.rate_h()
+                cap[s, u] = ops_u.cap
+                outage[s, u] = ops_u.outage
+                floor[s, u] = ops_u.floor_fraction
+                downscale[s, u] = ops_u.downscale_target
+                minq[s, u] = ops_u.min_queue_eff
+                origin_up[s, u] = ops_u.origin_up
+                dp_degrade_sbg[s, u] = ops_u.dp_degrade
+                dp_flush_sbg[s, u] = ops_u.flush_edge
                 if ops_u.scale_n is not None:
-                    n_unc[s, b] = ops_u.scale_n
+                    n_unc[s, u] = ops_u.scale_n
                 if ops_c.scale_n is not None:
-                    n_cap[s, b] = ops_c.scale_n
-        self.planes = {"rate": rate, "cap": cap, "outage": outage,
-                       "floor": floor, "downscale": downscale,
-                       "minq": minq, "n_unc": n_unc, "n_cap": n_cap}
+                    n_cap[s, u] = ops_c.scale_n
+        planes = {"rate": rate, "cap": cap, "outage": outage,
+                  "floor": floor, "downscale": downscale,
+                  "minq": minq, "n_unc": n_unc, "n_cap": n_cap}
         self.seg_of_tick = seg_of_tick
         self.is_seg_start = is_seg_start
 
         # count-plane geometry: W progress steps (one per dt until the
-        # job wall), L checkpoint levels, and the per-lane maps between
+        # job wall), L checkpoint levels, and the per-spec maps between
         # them (requeue level of a step; queue-drain start step)
-        lease = np.array([ln.spec.lease_interval_s for ln in self.lanes])
-        connected = lease[:, None] < g_nat[None, :]          # [B,G]
+        lease = np.array([ln.spec.lease_interval_s for ln in reps])
+        connected = lease[:, None] < g_nat[None, :]          # [U,G]
         nat_g = (~connected).astype(np.int32)
         self.nat_any = bool(nat_g.any())
-        wall = np.array([ln.spec.job_wall_h for ln in self.lanes])
-        ckpt = np.array([ln.spec.job_checkpoint_h for ln in self.lanes])
+        wall = np.array([ln.spec.job_wall_h for ln in reps])
+        ckpt = np.array([ln.spec.job_checkpoint_h for ln in reps])
         self.L = L = max(1, int(np.max(np.floor(wall / ckpt)) + 1))
         wfin1 = np.maximum(
             0, np.ceil(wall / self.dt - 1e-9).astype(np.int64) - 1)
         self.W = W = int(wfin1.max()) + 1
         finmask = (np.arange(W)[None, :] >= wfin1[:, None]) \
-            .astype(np.int32)                                # [B,W]
+            .astype(np.int32)                                # [U,W]
         lvl_of_w = np.minimum(np.floor(
             np.arange(W)[None, :] * self.dt / ckpt[:, None] + 1e-9)
             .astype(np.int64), L - 1)
-        M_wl = np.zeros((B, W, L), np.float32)
-        M_wl[np.arange(B)[:, None], np.arange(W)[None, :], lvl_of_w] = 1.0
+        M_wl = np.zeros((U, W, L), np.float32)
+        M_wl[np.arange(U)[:, None], np.arange(W)[None, :], lvl_of_w] = 1.0
         # queue drain order j: levels L-1..0 (highest checkpoint first),
         # then fresh (j = L) starting at step 0
         lev_of_j = np.concatenate([np.arange(L - 1, -1, -1), [0]])
@@ -732,9 +771,10 @@ class JaxSweepEngine:
             lev_of_j[None, :] * ckpt[:, None] / self.dt).astype(np.int64),
             W - 1)
         w0_of_j[:, L] = 0
-        M_jw = np.zeros((B, L + 1, W), np.float32)
-        M_jw[np.arange(B)[:, None], np.arange(L + 1)[None, :],
+        M_jw = np.zeros((U, L + 1, W), np.float32)
+        M_jw[np.arange(U)[:, None], np.arange(L + 1)[None, :],
              w0_of_j] = 1.0
+        lane_consts = {}
 
         # -- data plane: stage-in as a count-axis front extension.  A
         # matched job enters at ext position S_max + w0 - S and reaches
@@ -775,7 +815,7 @@ class JaxSweepEngine:
                  for o in origins_g])
             S_hit = _ticks(hbw_g)                            # [G]
             S_miss = _ticks(bw_g[None, None, :] * dp_degrade_sbg) \
-                .astype(np.int32)                            # [S,B,G]
+                .astype(np.int32)                            # [S,U,G]
             S_max = int(max(S_hit.max(), S_miss.max()))
             W_ext = W + S_max
             finmask = (np.arange(W_ext)[None, :]
@@ -784,24 +824,25 @@ class JaxSweepEngine:
                 np.arange(W_ext)[None, :] - S_max, 0, None)
                 * self.dt / ckpt[:, None] + 1e-9)
                 .astype(np.int64), L - 1)
-            M_wl = np.zeros((B, W_ext, L), np.float32)
-            M_wl[np.arange(B)[:, None], np.arange(W_ext)[None, :],
+            M_wl = np.zeros((U, W_ext, L), np.float32)
+            M_wl[np.arange(U)[:, None], np.arange(W_ext)[None, :],
                  lvl_of_ext] = 1.0
-            bi = np.arange(B)[:, None, None]
+            ui = np.arange(U)[:, None, None]
             gi = np.arange(G)[None, :, None]
             ji = np.arange(L + 1)[None, None, :]
             pos_hit = S_max + w0_of_j[:, None, :] \
-                - S_hit[None, :, None]                       # [B,G,L+1]
-            E_hit = np.zeros((B, G, L + 1, W_ext), np.float32)
-            E_hit[bi, gi, ji, pos_hit] = 1.0
-            E_miss = np.zeros((n_seg, B, G, L + 1, W_ext), np.float32)
+                - S_hit[None, :, None]                       # [U,G,L+1]
+            E_hit = np.zeros((U, G, L + 1, W_ext), np.float32)
+            E_hit[ui, gi, ji, pos_hit] = 1.0
+            E_miss = np.zeros((n_seg, U, G, L + 1, W_ext), np.float32)
             for s in range(n_seg):
                 pos_miss = S_max + w0_of_j[:, None, :] \
                     - S_miss[s][:, :, None]
-                E_miss[s][bi, gi, ji, pos_miss] = 1.0
-            self.planes["S_miss"] = S_miss
-            self.planes["E_miss"] = E_miss
-            self.planes["dp_flush"] = dp_flush_sbg
+                E_miss[s][ui, gi, ji, pos_miss] = 1.0
+            planes["S_miss"] = S_miss
+            planes["E_miss"] = E_miss
+            planes["dp_flush"] = dp_flush_sbg
+            lane_consts["E_hit"] = E_hit
             # expected hit-credit loss when a pilot's rotation resets:
             # over n stage-ins the rotation yields floor(n*r) hits, a
             # deficit of frac(n*r) vs the accumulator's exact n*r —
@@ -811,31 +852,36 @@ class JaxSweepEngine:
                 r_g > 0.0,
                 np.modf(n_ * r_g[None, :].astype(np.float64))[0].mean(0),
                 0.0).astype(np.float32)
-            self._dp_consts = {"dp_r_g": r_g, "dp_has_g": dp_has_g,
-                               "dp_usd_miss_g": usd_miss_g,
-                               "dp_loss_g": loss_g,
-                               "S_hit_g": S_hit.astype(np.float32),
-                               "E_hit": E_hit}
+            dp_consts = {"dp_r_g": r_g, "dp_has_g": dp_has_g,
+                         "dp_usd_miss_g": usd_miss_g, "dp_loss_g": loss_g,
+                         "S_hit_g": S_hit.astype(np.float32)}
         else:
-            self._dp_consts = {}
+            dp_consts = {}
         if self.dp_active:
-            self.planes["origin_up"] = origin_up
+            planes["origin_up"] = origin_up
+        self.planes = {k: at_lanes(v, 1) for k, v in planes.items()}
+        lane_consts.update(
+            nat_g=nat_g, finmask=finmask, M_wl=M_wl, M_jw=M_jw,
+            overhead=np.array([ln.spec.overhead_per_day for ln in reps],
+                              np.float32),
+            budget=np.array([ln.spec.budget for ln in reps], np.float32))
+        lc = {k: at_lanes(v, 0) for k, v in lane_consts.items()}
+        if self.dp_staging:
+            dp_consts["E_hit"] = lc["E_hit"]
 
         self.consts = {
             "prov_onehot": prov_onehot,
             "pre_rate_g": g_pre_rate,
             "pre_scale_g": g_pre_scale,
-            "nat_g": nat_g,
-            "finmask_rg": np.repeat(finmask, G, axis=0),     # [B*G,W]
-            "M_wl": M_wl,
-            "M_jw": M_jw,
-            "overhead": np.array([ln.spec.overhead_per_day
-                                  for ln in self.lanes], np.float32),
-            "budget": np.array([ln.spec.budget for ln in self.lanes],
-                               np.float32),
+            "nat_g": lc["nat_g"],
+            "finmask_rg": np.repeat(lc["finmask"], G, axis=0),  # [B*G,W]
+            "M_wl": lc["M_wl"],
+            "M_jw": lc["M_jw"],
+            "overhead": lc["overhead"],
+            "budget": lc["budget"],
             "dt": np.float32(self.dt),
             "seeds": np.array([ln.seed for ln in self.lanes], np.uint32),
-            **self._dp_consts,
+            **dp_consts,
         }
         assert (self.consts["budget"] > 0).all(), \
             "sweep lanes need a budget"
@@ -983,7 +1029,16 @@ def run_jax_detailed(lane_specs: Sequence[Tuple[CampaignSpec, int]],
     statistical engine does not implement)."""
     with obs.call("engine.call"):
         with obs.span("engine.prepare"):
-            prepared = [_prepare(sc, seed) for sc, seed in lane_specs]
+            # preparing is a function of the spec alone: once per
+            # distinct spec, and its other lanes share the result
+            u_of, firsts = _distinct([sc for sc, _seed in lane_specs])
+            prepared: List[Tuple[tuple, _Lane]] = []
+            for i, ((sc, seed), u) in enumerate(zip(lane_specs, u_of)):
+                if i == firsts[u]:
+                    prepared.append(_prepare(sc, seed))
+                else:
+                    key, ln = prepared[firsts[u]]
+                    prepared.append((key, _Lane(ln.spec, seed, ln.pairs)))
         batches: Dict[tuple, List[int]] = {}
         for i, (key, _lane) in enumerate(prepared):
             batches.setdefault(key, []).append(i)

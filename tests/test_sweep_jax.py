@@ -185,3 +185,329 @@ def test_jax_pallas_interpret_path_matches_ref_path():
     ref = run_jax(lanes, use_pallas=False)
     pal = run_jax(lanes, use_pallas=True)
     assert ref == pal
+
+
+# -- the bake runs once per distinct spec ------------------------------------
+
+def _bake_per_lane(lanes):
+    """The bake as it was done lane by lane before it was shared per
+    spec: the oracle for ``JaxSweepEngine.__init__``'s planes and
+    constants.  Returns ``(planes, consts, seg_of_tick, is_seg_start)``."""
+    import numpy as np
+
+    from repro.core import timeline as timeline_registry
+    from repro.core.sweep_jax import JaxLaneOps
+
+    B, ref = len(lanes), lanes[0]
+    pairs = ref.pairs
+    G = len(pairs)
+    dt, duration = float(ref.spec.dt_h), float(ref.spec.duration_h)
+    g_provider = [p.name for p, _ in pairs]
+    providers = list(dict.fromkeys(g_provider))
+    prov_onehot = np.zeros((G, len(providers)), np.float32)
+    prov_onehot[np.arange(G), [providers.index(n) for n in g_provider]] = 1.0
+    g_nat = np.array([p.nat_idle_timeout_s for p, _ in pairs])
+    times, now = [], 0.0
+    while now < duration:
+        times.append(now)
+        now += dt
+    tick_times = np.array(times)
+    N = len(times)
+
+    evs_b, fts_b, seg_set = [], [], {0}
+    for ln in lanes:
+        evs = timeline_registry.compile_timeline(ln.spec.timeline)
+        ft = np.searchsorted(tick_times, np.array([e[0] for e in evs]),
+                             "left") if evs else np.zeros(0, np.int64)
+        evs_b.append(evs)
+        fts_b.append(ft)
+        seg_set.update(int(t) for t in ft if t < N)
+    seg_ticks = np.array(sorted(seg_set), np.int64)
+    n_seg = len(seg_ticks)
+    seg_of_tick = (np.searchsorted(seg_ticks, np.arange(N), "right")
+                   - 1).astype(np.int32)
+    is_seg_start = np.zeros(N, bool)
+    is_seg_start[seg_ticks] = True
+
+    rate = np.zeros((n_seg, B, G), np.float32)
+    cap = np.zeros((n_seg, B, G), np.int32)
+    outage = np.zeros((n_seg, B), bool)
+    floor = np.zeros((n_seg, B), np.float32)
+    downscale = np.zeros((n_seg, B), np.int32)
+    minq = np.zeros((n_seg, B), np.int32)
+    n_unc = np.full((n_seg, B), -1, np.int32)
+    n_cap = np.full((n_seg, B), -1, np.int32)
+    origin_up = np.ones((n_seg, B, G), bool)
+    dp_degrade = np.ones((n_seg, B, G))
+    dp_flush = np.zeros((n_seg, B, G), bool)
+    for b, ln in enumerate(lanes):
+        ops_u = JaxLaneOps(ln.spec, ln.pairs, budget_capped=False)
+        ops_c = JaxLaneOps(ln.spec, ln.pairs, budget_capped=True)
+        by_tick = {}
+        for (_t, kind, arg), ft in zip(evs_b[b], fts_b[b]):
+            if ft < N:
+                by_tick.setdefault(int(ft), []).append((kind, arg))
+        for s, st in enumerate(seg_ticks):
+            ops_u.scale_n = ops_c.scale_n = None
+            ops_u.flush_edge[:] = False
+            for kind, arg in by_tick.get(int(st), []):
+                timeline_registry.apply_op(ops_u, kind, arg, 0.0)
+                timeline_registry.apply_op(ops_c, kind, arg, 0.0)
+            rate[s, b] = ops_u.rate_h()
+            cap[s, b] = ops_u.cap
+            outage[s, b] = ops_u.outage
+            floor[s, b] = ops_u.floor_fraction
+            downscale[s, b] = ops_u.downscale_target
+            minq[s, b] = ops_u.min_queue_eff
+            origin_up[s, b] = ops_u.origin_up
+            dp_degrade[s, b] = ops_u.dp_degrade
+            dp_flush[s, b] = ops_u.flush_edge
+            if ops_u.scale_n is not None:
+                n_unc[s, b] = ops_u.scale_n
+            if ops_c.scale_n is not None:
+                n_cap[s, b] = ops_c.scale_n
+    planes = {"rate": rate, "cap": cap, "outage": outage, "floor": floor,
+              "downscale": downscale, "minq": minq, "n_unc": n_unc,
+              "n_cap": n_cap}
+
+    lease = np.array([ln.spec.lease_interval_s for ln in lanes])
+    nat_g = (~(lease[:, None] < g_nat[None, :])).astype(np.int32)
+    wall = np.array([ln.spec.job_wall_h for ln in lanes])
+    ckpt = np.array([ln.spec.job_checkpoint_h for ln in lanes])
+    L = max(1, int(np.max(np.floor(wall / ckpt)) + 1))
+    wfin1 = np.maximum(0, np.ceil(wall / dt - 1e-9).astype(np.int64) - 1)
+    W = int(wfin1.max()) + 1
+    finmask = (np.arange(W)[None, :] >= wfin1[:, None]).astype(np.int32)
+    lvl_of_w = np.minimum(np.floor(np.arange(W)[None, :] * dt
+                                   / ckpt[:, None] + 1e-9)
+                          .astype(np.int64), L - 1)
+    M_wl = np.zeros((B, W, L), np.float32)
+    M_wl[np.arange(B)[:, None], np.arange(W)[None, :], lvl_of_w] = 1.0
+    lev_of_j = np.concatenate([np.arange(L - 1, -1, -1), [0]])
+    w0_of_j = np.minimum(np.rint(lev_of_j[None, :] * ckpt[:, None] / dt)
+                         .astype(np.int64), W - 1)
+    w0_of_j[:, L] = 0
+    M_jw = np.zeros((B, L + 1, W), np.float32)
+    M_jw[np.arange(B)[:, None], np.arange(L + 1)[None, :], w0_of_j] = 1.0
+
+    dp = ref.spec.dataplane
+    dp_size = float(ref.spec.job_input_gb)
+    origins_g = [dp.origin_for(n) if dp is not None else None
+                 for n in g_provider]
+    dp_active = dp is not None and bool(dp.origins)
+    dp_consts = {}
+    if dp_active and dp_size > 0.0:
+        def ticks(gbps):
+            gbps = np.asarray(gbps, np.float64)
+            hours = dp_size * 8.0 / np.where(gbps > 0.0, gbps, 1.0) / 3600.0
+            t = np.maximum(1, np.ceil(hours / dt - 1e-9).astype(np.int64))
+            return np.where(gbps > 0.0, t, 0)
+
+        r_g = np.array([o.cache_hit_rate if o else 0.0 for o in origins_g],
+                       np.float32)
+        bw_g = np.array([o.bandwidth_gbps if o else 0.0 for o in origins_g])
+        hbw_g = np.array([(o.cache_bandwidth_gbps
+                           if o.cache_bandwidth_gbps > 0.0
+                           else o.bandwidth_gbps) if o else 0.0
+                          for o in origins_g])
+        S_hit = ticks(hbw_g)
+        S_miss = ticks(bw_g[None, None, :] * dp_degrade).astype(np.int32)
+        S_max = int(max(S_hit.max(), S_miss.max()))
+        W_ext = W + S_max
+        finmask = (np.arange(W_ext)[None, :]
+                   >= S_max + wfin1[:, None]).astype(np.int32)
+        lvl_of_ext = np.minimum(np.floor(
+            np.clip(np.arange(W_ext)[None, :] - S_max, 0, None)
+            * dt / ckpt[:, None] + 1e-9).astype(np.int64), L - 1)
+        M_wl = np.zeros((B, W_ext, L), np.float32)
+        M_wl[np.arange(B)[:, None], np.arange(W_ext)[None, :],
+             lvl_of_ext] = 1.0
+        bi = np.arange(B)[:, None, None]
+        gi = np.arange(G)[None, :, None]
+        ji = np.arange(L + 1)[None, None, :]
+        E_hit = np.zeros((B, G, L + 1, W_ext), np.float32)
+        E_hit[bi, gi, ji, S_max + w0_of_j[:, None, :]
+              - S_hit[None, :, None]] = 1.0
+        E_miss = np.zeros((n_seg, B, G, L + 1, W_ext), np.float32)
+        for s in range(n_seg):
+            E_miss[s][bi, gi, ji, S_max + w0_of_j[:, None, :]
+                      - S_miss[s][:, :, None]] = 1.0
+        planes.update(S_miss=S_miss, E_miss=E_miss, dp_flush=dp_flush)
+        n_ = np.arange(1, 201)[:, None]
+        dp_consts = {
+            "dp_r_g": r_g,
+            "dp_has_g": np.array([o is not None for o in origins_g],
+                                 np.float32),
+            "dp_usd_miss_g": np.array(
+                [dp_size * o.egress_usd_per_gb if o else 0.0
+                 for o in origins_g], np.float32),
+            "dp_loss_g": np.where(
+                r_g > 0.0,
+                np.modf(n_ * r_g[None, :].astype(np.float64))[0].mean(0),
+                0.0).astype(np.float32),
+            "S_hit_g": S_hit.astype(np.float32),
+            "E_hit": E_hit}
+    if dp_active:
+        planes["origin_up"] = origin_up
+    consts = {
+        "prov_onehot": prov_onehot,
+        "pre_rate_g": np.array([r.preempt_rate_per_hour for _, r in pairs],
+                               np.float32),
+        "pre_scale_g": np.array([r.preempt_scale_at_full for _, r in pairs],
+                                np.float32),
+        "nat_g": nat_g,
+        "finmask_rg": np.repeat(finmask, G, axis=0),
+        "M_wl": M_wl,
+        "M_jw": M_jw,
+        "overhead": np.array([ln.spec.overhead_per_day for ln in lanes],
+                             np.float32),
+        "budget": np.array([ln.spec.budget for ln in lanes], np.float32),
+        "dt": np.float32(dt),
+        "seeds": np.array([ln.seed for ln in lanes], np.uint32),
+        **dp_consts,
+    }
+    return planes, consts, seg_of_tick, is_seg_start
+
+
+def _json_copy(spec):
+    """An equal spec that is another object (a JSON round trip)."""
+    import json
+
+    from repro.core.spec import CampaignSpec
+    copy = CampaignSpec.from_dict(json.loads(spec.to_json()))
+    assert copy == spec and copy is not spec
+    return copy
+
+
+def _paper_mix():
+    """Paper-catalog specs, 72 h: planning-grid variants, an outage-grid
+    member, workload and price curves, and shifts of price, capacity and
+    the budget floor — one batch key, timelines that differ."""
+    from dataclasses import replace
+
+    from repro.core.timeline import BudgetFloor, CapacityShift, PriceShift
+    short = {"duration_h": 72.0}
+    grid = [replace(s, **short) for s in scenarios.planning_grid(
+        (0.9, 1.1), (0.2, 0.3), (58000.0,))]
+    outage = replace(scenarios.outage_grid((30.0,), (2.0,))[0], **short)
+    shifts = replace(grid[0], name="shifts", timeline=(
+        scenarios.PAPER_RAMP_EVENTS[:3] + (
+            BudgetFloor(20.0, 0.3, 500), PriceShift(30.0, 1.2),
+            CapacityShift(40.0, 0.5), PriceShift(50.0, 0.9))))
+    return grid + [outage, shifts, _short("load-diurnal", **short),
+                   _short("curve-drift-up", **short)]
+
+
+def _dataplane_mix():
+    """Data-plane specs, 72 h, with an origin outage, a degrade and a
+    cache flush inside the window: one batch key."""
+    from dataclasses import replace
+
+    from repro.core.timeline import CacheFlush, OriginDegrade, OriginOutage
+    base = replace(scenarios.dataplane_burst(), duration_h=72.0,
+                   timeline=scenarios.PAPER_RAMP_EVENTS[:3] + (
+                       OriginOutage(10.0, 6.0, "azure"),
+                       OriginDegrade(20.0, 0.5, "aws"),
+                       CacheFlush(30.0, "azure"),
+                       OriginDegrade(40.0, 0.5, "aws")))
+    late = replace(base, name="late", budget=40000.0,
+                   timeline=scenarios.PAPER_RAMP_EVENTS[:3] + (
+                       CacheFlush(25.0, "azure"),
+                       OriginOutage(35.0, 4.0, "gcp")))
+    return [base, late]
+
+
+def _mixed_lanes(specs):
+    """Every spec under repeated seeds, once more as the same object and
+    once as an equal copy, interleaved."""
+    lanes = []
+    for i, sc in enumerate(specs):
+        lanes += [(sc, 7), (_json_copy(sc), 3 + i % 2), (sc, 3)]
+    return lanes[0::2] + lanes[1::2]
+
+
+@pytest.mark.parametrize("mix", ["paper", "dataplane", "distinct"])
+def test_bake_per_spec_equals_the_per_lane_bake(mix):
+    """Baking once per distinct spec and gathering to the lanes gives
+    every plane and constant of the per-lane bake, bit for bit: equal
+    specs as one object or as copies, seeds repeated across specs, and
+    (``distinct``) a batch in which no two lanes share a spec."""
+    import numpy as np
+
+    from repro.core.sweep_jax import JaxSweepEngine
+    from repro.core.timeline import compile_timeline
+    if mix == "distinct":
+        lane_specs = [(s, i) for i, s in enumerate(_paper_mix())]
+    else:
+        specs = _paper_mix() if mix == "paper" else _dataplane_mix()
+        lane_specs = _mixed_lanes(specs)
+    prepared = [_prepare(sc, seed) for sc, seed in lane_specs]
+    assert len({key for key, _ln in prepared}) == 1
+    lanes = [ln for _key, ln in prepared]
+    eng = JaxSweepEngine(lanes, use_pallas=False)
+    planes, consts, seg_of_tick, is_seg_start = _bake_per_lane(lanes)
+    if mix == "dataplane":
+        assert {"E_miss", "origin_up", "dp_flush"} <= set(eng.planes)
+        assert eng.planes["dp_flush"].any()
+        assert not eng.planes["origin_up"].all()
+    assert set(eng.planes) == set(planes)
+    assert set(eng.consts) == set(consts)
+    for got, want in ((eng.planes, planes), (eng.consts, consts)):
+        for k, v in want.items():
+            assert got[k].dtype == v.dtype and got[k].shape == v.shape, k
+            assert np.array_equal(got[k], v), k
+    assert np.array_equal(eng.seg_of_tick, seg_of_tick)
+    assert np.array_equal(eng.is_seg_start, is_seg_start)
+    assert eng._evs == [compile_timeline(ln.spec.timeline) for ln in lanes]
+
+
+def test_distinct_rows_by_value_and_identity():
+    from repro.core.sweep_jax import _distinct
+    a, b = _short("paper"), _short("floor30")
+    unhashable = [1]
+    rows, firsts = _distinct([a, b, _json_copy(a), a, unhashable, b,
+                              unhashable, [1]])
+    assert rows == [0, 1, 0, 0, 2, 1, 2, 3]
+    assert firsts == [0, 1, 4, 7]
+
+
+def test_run_jax_prepares_once_per_spec_and_keeps_input_order(monkeypatch):
+    """Lanes of two batch keys, interleaved, with an equal copy of one
+    spec: ``_prepare`` runs once per distinct spec, and the rows and
+    ``events_fired`` come back in input order, equal to those of each
+    spec's lanes run on their own; ``engine.bake`` counts the lanes and
+    the distinct specs it baked."""
+    from repro import obs
+    from repro.core import sweep_jax
+    a = _short(duration_h=24.0)
+    h = _short("hetero", duration_h=24.0)
+    a2 = _json_copy(a)
+    lane_specs = [(a, 0), (h, 5), (a2, 1), (a, 2), (h, 0), (a2, 0)]
+    calls = []
+    prepare = sweep_jax._prepare
+
+    def counted(sc, seed):
+        calls.append(sc)
+        return prepare(sc, seed)
+
+    monkeypatch.setattr(sweep_jax, "_prepare", counted)
+    got = sweep_jax.run_jax_detailed(lane_specs)
+    assert calls == [a, h]
+    bake = [(s.counters["bake_lanes"], s.counters["bake_specs"])
+            for s in obs.calls()[-1].spans if s.name == "engine.bake"]
+    assert bake == [(4, 1), (2, 1)]
+
+    def alone(sc, seeds):
+        eng = sweep_jax.JaxSweepEngine(
+            [prepare(sc, seed)[1] for seed in seeds]).run()
+        return [(eng.lane_results(j), eng.lane_events(j), None)
+                for j in range(len(seeds))]
+
+    alone_a = alone(a, [0, 1, 2, 0])
+    alone_h = alone(h, [5, 0])
+    want = [alone_a[0], alone_h[0], alone_a[1], alone_a[2], alone_h[1],
+            alone_a[3]]
+    assert len(got) == len(want)
+    for (res, evs, tr), (res_w, evs_w, _tr) in zip(got, want):
+        assert res == res_w and evs == evs_w and tr is None
+    assert got[0] == got[5] and got[0][0] != got[2][0]
