@@ -55,6 +55,7 @@ the other engines' schema exactly.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -272,11 +273,27 @@ def _poisson(u, lam):
 _EXACT = jax.lax.Precision.HIGHEST
 
 
-@functools.partial(jax.jit, static_argnames=("nat_any", "use_pallas",
-                                             "dp_gating", "dp_staging"))
-def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
-                    dp_gating=False, dp_staging=False):
-    """One jitted lax.scan over all N ticks of B lock-step lanes.
+def _state_shapes(B: int, G: int, W: int, L: int, P: int
+                  ) -> Dict[str, Tuple[tuple, str]]:
+    """The scan's carry, name -> (shape, dtype); its outputs are the
+    final carry and ``live_g``."""
+    i, f, b = "int32", "float32", "bool"
+    return {
+        "idle": ((B, G), i), "pdead": ((B, G), i), "busy": ((B, G, W), i),
+        "target_g": ((B, G), i), "lv": ((B, L), i), "fresh_q": ((B,), i),
+        "spent": ((B,), f), "by_prov": ((B, P), f), "infra": ((B,), f),
+        "fired": ((B, len(_THRESHOLDS)), b), "capped": ((B,), b),
+        "cap_pending": ((B,), b), "cap_tick": ((B,), i),
+        "pre_ct": ((B,), i), "nat_ct": ((B,), i), "fin_ct": ((B,), i),
+        "accel": ((B,), f), "busy_h": ((B,), f), "busy_prov": ((B, P), f),
+        "hit_acc": ((B, G), f), "virgin": ((B, G), f), "hits": ((B,), f),
+        "misses": ((B,), f), "stage_t": ((B,), f), "egress_g": ((B, G), f),
+    }
+
+
+def _scan(planes, consts, xs, *, nat_any, use_pallas,
+          dp_gating=False, dp_staging=False):
+    """One lax.scan over all N ticks of B lock-step lanes.
 
     The tick phases mirror ``BatchedFleetEngine.tick`` (see that
     module): events, kill-to-target, spawn, preemption, queue top-up,
@@ -550,33 +567,9 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
                 "stage_t": stage_t, "egress_g": egress_g,
                 "virgin": virgin}, None
 
-    init = {
-        "idle": jnp.zeros((B, G), jnp.int32),
-        "pdead": jnp.zeros((B, G), jnp.int32),
-        "busy": jnp.zeros((B, G, W), jnp.int32),
-        "target_g": jnp.zeros((B, G), jnp.int32),
-        "lv": jnp.zeros((B, L), jnp.int32),
-        "fresh_q": jnp.zeros((B,), jnp.int32),
-        "spent": jnp.zeros((B,), jnp.float32),
-        "by_prov": jnp.zeros((B, P), jnp.float32),
-        "infra": jnp.zeros((B,), jnp.float32),
-        "fired": jnp.zeros((B, len(_THRESHOLDS)), bool),
-        "capped": jnp.zeros((B,), bool),
-        "cap_pending": jnp.zeros((B,), bool),
-        "cap_tick": jnp.full((B,), -1, jnp.int32),
-        "pre_ct": jnp.zeros((B,), jnp.int32),
-        "nat_ct": jnp.zeros((B,), jnp.int32),
-        "fin_ct": jnp.zeros((B,), jnp.int32),
-        "accel": jnp.zeros((B,), jnp.float32),
-        "busy_h": jnp.zeros((B,), jnp.float32),
-        "busy_prov": jnp.zeros((B, P), jnp.float32),
-        "hit_acc": jnp.zeros((B, G), jnp.float32),
-        "virgin": jnp.zeros((B, G), jnp.float32),
-        "hits": jnp.zeros((B,), jnp.float32),
-        "misses": jnp.zeros((B,), jnp.float32),
-        "stage_t": jnp.zeros((B,), jnp.float32),
-        "egress_g": jnp.zeros((B, G), jnp.float32),
-    }
+    init = {k: jnp.zeros(shape, dtype) for k, (shape, dtype)
+            in _state_shapes(B, G, W, L, P).items()}
+    init["cap_tick"] = jnp.full((B,), -1, jnp.int32)
     out, _ = jax.lax.scan(step, init, xs)
 
     # settle the final interval: one more dt at last-segment rates
@@ -587,6 +580,117 @@ def _scan_campaigns(planes, consts, xs, *, nat_any, use_pallas,
         + jnp.matmul(amt, prov_onehot, precision=_EXACT)
     out["live_g"] = live_final
     return out
+
+
+# -- one packed buffer each way --------------------------------------------
+#
+# A host<->device transfer costs about the same whatever its size, so the
+# scan's arguments go to the device as one flat uint32 buffer and its
+# outputs come back as another, in place of one transfer per array.
+# Every array the scan takes or gives is 32-bit or bool: one word an
+# element, bools as 0/1.  Each array starts on a row of 128 words, the
+# lane width of a TPU tile: at unaligned starts the TPU compiler took
+# about three times as long over the 1,020-lane batch's unpacking.
+
+_WORD_DTYPES = ("float32", "int32", "uint32", "bool")
+_ALIGN_WORDS = 128
+
+
+def _layout(groups: Dict[str, dict]) -> tuple:
+    """Where each array of ``groups`` (group -> key -> anything with a
+    ``shape`` and a ``dtype``) lies in one packed buffer: a hashable
+    tuple of ``(group, key, offset, shape, dtype)``, offsets in words,
+    a function of keys, shapes and dtypes alone."""
+    layout, off = [], 0
+    for g, arrays in groups.items():
+        for k, a in arrays.items():
+            dtype = a.dtype.name
+            if dtype not in _WORD_DTYPES:
+                raise TypeError(f"{g}[{k!r}] is {dtype}: only "
+                                f"{', '.join(_WORD_DTYPES)} pack")
+            shape = tuple(a.shape)
+            layout.append((g, k, off, shape, dtype))
+            off += -(-math.prod(shape) // _ALIGN_WORDS) * _ALIGN_WORDS
+    return tuple(layout)
+
+
+def _pack(groups: Dict[str, dict], layout: tuple):
+    """The arrays of ``groups`` in one uint32 buffer, placed as
+    ``layout`` says, with zeros between them: host arrays fill one numpy
+    buffer in place, traced ones are concatenated inside the jit (where
+    the groups' keys, shapes and dtypes are checked against the
+    layout's, in any order)."""
+    arrays = [groups[g][k] for g, k, _off, _shape, _dtype in layout]
+    if any(isinstance(a, jax.Array) for a in arrays):
+        assert sorted(e[:2] + e[3:] for e in _layout(groups)) \
+            == sorted(e[:2] + e[3:] for e in layout), "arrays, layout differ"
+        words, end = [], 0
+        for a, (_g, _k, off, _shape, dtype) in zip(arrays, layout):
+            words.append(jnp.zeros(off - end, jnp.uint32))
+            words.append((a.astype(jnp.uint32) if dtype == "bool" else
+                          jax.lax.bitcast_convert_type(a, jnp.uint32))
+                         .reshape(-1))
+            end = off + a.size
+        return jnp.concatenate(words)
+    _g, _k, off, shape, _d = layout[-1]
+    buf = np.empty(off + math.prod(shape), np.uint32)
+    end = 0
+    for a, (_g, _k, off, _shape, dtype) in zip(arrays, layout):
+        a = np.asarray(a).reshape(-1)
+        buf[end:off] = 0
+        buf[off:off + a.size] = a if dtype == "bool" else a.view(np.uint32)
+        end = off + a.size
+    return buf
+
+
+def _unpack(buf, layout: tuple) -> Dict[str, dict]:
+    """The arrays ``layout`` places in ``buf``, by group and key: views
+    of a numpy buffer, or inside a jit static slices of a traced one."""
+    groups: Dict[str, dict] = {}
+    for g, k, off, shape, dtype in layout:
+        w = buf[off:off + math.prod(shape)].reshape(shape)
+        if dtype == "bool":
+            a = w != 0
+        elif isinstance(w, np.ndarray):
+            a = w.view(dtype)
+        else:
+            a = jax.lax.bitcast_convert_type(w, dtype)
+        groups.setdefault(g, {})[k] = a
+    return groups
+
+
+@functools.lru_cache(maxsize=None)
+def _out_layout(B: int, G: int, W: int, L: int, P: int) -> tuple:
+    """The layout of the scan's packed outputs, from the batch's sizes."""
+    shapes = dict(_state_shapes(B, G, W, L, P), live_g=((B, G), "int32"))
+    return _layout({"out": {k: jax.ShapeDtypeStruct(shape, dtype)
+                            for k, (shape, dtype) in shapes.items()}})
+
+
+def _out_sizes(consts) -> Tuple[int, int, int, int, int]:
+    """(B, G, W, L, P) of a batch, from its constants' shapes."""
+    B, G = consts["nat_g"].shape
+    _B, W, L = consts["M_wl"].shape
+    return B, G, W, L, consts["prov_onehot"].shape[1]
+
+
+@functools.partial(jax.jit, static_argnames=("layout", "nat_any",
+                                             "use_pallas", "dp_gating",
+                                             "dp_staging"))
+def _scan_campaigns(buf, *, layout, nat_any, use_pallas,
+                    dp_gating=False, dp_staging=False):
+    """The scan's one jitted entry: unpacks ``buf`` (planes, constants
+    and per-tick inputs, placed as ``layout`` says; see :func:`_pack`),
+    runs :func:`_scan` and returns its outputs packed in one uint32
+    buffer as ``_out_layout`` places them.  The optimization barrier
+    hands the scan standalone arrays, so that XLA folds no slice of the
+    buffer into the loop."""
+    args = _unpack(buf, layout)
+    planes, consts, xs = jax.lax.optimization_barrier(
+        (args["planes"], args["consts"], tuple(args["xs"].values())))
+    out = _scan(planes, consts, xs, nat_any=nat_any, use_pallas=use_pallas,
+                dp_gating=dp_gating, dp_staging=dp_staging)
+    return _pack({"out": out}, _out_layout(*_out_sizes(consts)))
 
 
 # -- batch construction ----------------------------------------------------
@@ -888,15 +992,16 @@ class JaxSweepEngine:
         self.out: Optional[dict] = None
 
     def _scan_call(self):
-        xs = (np.arange(self.N, dtype=np.int32),
-              self.seg_of_tick,
-              self.is_seg_start)
-        args = ({k: jnp.asarray(v) for k, v in self.planes.items()},
-                {k: jnp.asarray(v) for k, v in self.consts.items()},
-                tuple(jnp.asarray(v) for v in xs))
-        return args, dict(nat_any=self.nat_any, use_pallas=self.use_pallas,
-                          dp_gating=self.dp_active,
-                          dp_staging=self.dp_staging)
+        """The scan's one argument, packed and put on the device, and
+        its static options."""
+        groups = {"planes": self.planes, "consts": self.consts,
+                  "xs": {"tick": np.arange(self.N, dtype=np.int32),
+                         "seg": self.seg_of_tick,
+                         "start": self.is_seg_start}}
+        layout = _layout(groups)
+        return (jnp.asarray(_pack(groups, layout)),), dict(
+            layout=layout, nat_any=self.nat_any, use_pallas=self.use_pallas,
+            dp_gating=self.dp_active, dp_staging=self.dp_staging)
 
     def lower(self):
         """The scan exactly as :meth:`run` dispatches it, lowered:
@@ -906,7 +1011,8 @@ class JaxSweepEngine:
 
     def run(self) -> "JaxSweepEngine":
         """Stages the arguments, dispatches the scan, waits for the
-        device and copies the outputs back: one ``obs`` span each."""
+        device and copies the outputs back: one ``obs`` span each, one
+        transfer each way."""
         with obs.span("engine.put"):
             args, kw = self._scan_call()
             staged = jax.tree.leaves(args)
@@ -917,9 +1023,11 @@ class JaxSweepEngine:
         with obs.span("engine.wait"):
             out = jax.block_until_ready(out)
         with obs.span("engine.fetch"):
-            self.out = {k: np.asarray(v) for k, v in out.items()}
-            obs.count("d2h_bytes", sum(v.nbytes for v in self.out.values()))
-            obs.count("d2h_arrays", len(self.out))
+            buf = np.asarray(out)
+            layout = _out_layout(*_out_sizes(self.consts))
+            self.out = _unpack(buf, layout)["out"]
+            obs.count("d2h_bytes", buf.nbytes)
+            obs.count("d2h_arrays", 1)
         return self
 
     # -- per-lane provenance + results ------------------------------------

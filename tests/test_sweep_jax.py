@@ -511,3 +511,128 @@ def test_run_jax_prepares_once_per_spec_and_keeps_input_order(monkeypatch):
     for (res, evs, tr), (res_w, evs_w, _tr) in zip(got, want):
         assert res == res_w and evs == evs_w and tr is None
     assert got[0] == got[5] and got[0][0] != got[2][0]
+
+
+# -- staging: one packed buffer each way -----------------------------------
+
+def _bits(a):
+    """What a bit-for-bit comparison compares: dtype, shape and bytes."""
+    import numpy as np
+    a = np.asarray(a)
+    return a.dtype.name, a.shape, a.tobytes()
+
+
+def _word_arrays():
+    """Arrays of every dtype a packed buffer carries: signed zero, the
+    infinities and a NaN with a payload, the integers' extremes, bools,
+    a 0-d scalar and a 5-D array."""
+    import numpy as np
+    rng = np.random.default_rng(5)
+    nan = np.array([0x7FC00123], np.uint32).view(np.float32)[0]
+    return {
+        "planes": {
+            "f": np.array([0.0, -0.0, np.inf, -np.inf, nan, 1e-45, -3.5],
+                          np.float32),
+            "m": rng.random((2, 3, 4)) < 0.5,
+            "e5": rng.standard_normal((2, 1, 3, 2, 4)).astype(np.float32)},
+        "consts": {
+            "i": np.array([[-2 ** 31, -1, 0], [1, 7, 2 ** 31 - 1]],
+                          np.int32),
+            "dt": np.float32(0.25),
+            "u": np.array([0, 1, 2 ** 32 - 1, 2 ** 31], np.uint32),
+            "one": np.array([True])}}
+
+
+def test_pack_round_trips_bit_for_bit():
+    """Host packing, unpacking on the host and inside a jit, and
+    packing inside a jit all keep every array's bits, dtype and
+    shape; the layout is a hashable function of keys, shapes and
+    dtypes alone, and starts each array on a row of 128 words, with
+    no overlap and no gap of a row or more."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.sweep_jax import _layout, _pack, _unpack
+    groups = _word_arrays()
+    want = {g: {k: _bits(a) for k, a in arrs.items()}
+            for g, arrs in groups.items()}
+    layout = _layout(groups)
+    hash(layout)
+    assert layout == _layout(
+        {g: {k: jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+             for k, a in arrs.items()} for g, arrs in groups.items()})
+    offs = [off for _g, _k, off, _s, _d in layout]
+    ends = [off + int(np.prod(shape)) for _g, _k, off, shape, _d in layout]
+    assert offs[0] == 0 and all(off % 128 == 0 for off in offs)
+    assert all(0 <= nxt - end < 128 for end, nxt in zip(ends, offs[1:]))
+    buf = _pack(groups, layout)
+    assert buf.dtype == np.uint32 and buf.shape == (ends[-1],)
+
+    def bits(got):
+        return {g: {k: _bits(a) for k, a in arrs.items()}
+                for g, arrs in got.items()}
+
+    assert bits(_unpack(buf, layout)) == want
+    on_device = jax.jit(_unpack, static_argnums=1)(jnp.asarray(buf), layout)
+    assert bits(on_device) == want
+    packed = jax.jit(_pack, static_argnums=1)(
+        jax.tree.map(jnp.asarray, groups), layout)
+    assert _bits(packed) == _bits(buf)
+
+
+def test_layout_refuses_an_array_that_is_not_one_word():
+    import numpy as np
+
+    from repro.core.sweep_jax import _layout
+    with pytest.raises(TypeError, match="float64"):
+        _layout({"consts": {"dt": np.float64(0.25)}})
+
+
+@pytest.mark.parametrize("variant", ["plain", "dataplane", "nat"])
+def test_packed_run_equals_the_unpacked_scan(variant):
+    """``run``'s packed entry gives every output of a jit of the scan
+    itself, fed the engine's dicts, bit for bit: the plain scan, the
+    data plane (``dp_gating`` and ``dp_staging``) and a lease over
+    Azure's 240 s NAT timeout (``nat_any``)."""
+    import jax
+    import numpy as np
+
+    from repro.core import sweep_jax
+    from repro.core.spec import CampaignSpec
+    spec = {"plain": CampaignSpec(),
+            "dataplane": scenarios.dataplane_burst(),
+            "nat": CampaignSpec(lease_interval_s=300.0)}[variant]
+    eng = sweep_jax.JaxSweepEngine(
+        [_prepare(spec, s)[1] for s in (0, 3, 2 ** 31 - 1)],
+        use_pallas=False)
+    flags = dict(nat_any=eng.nat_any, use_pallas=False,
+                 dp_gating=eng.dp_active, dp_staging=eng.dp_staging)
+    assert (flags["nat_any"], flags["dp_gating"], flags["dp_staging"]) \
+        == {"plain": (False, False, False), "dataplane": (False, True, True),
+            "nat": (True, False, False)}[variant]
+    eng.run()
+    scan = jax.jit(sweep_jax._scan, static_argnames=list(flags))
+    want = scan(eng.planes, eng.consts,
+                (np.arange(eng.N, dtype=np.int32), eng.seg_of_tick,
+                 eng.is_seg_start), **flags)
+    assert set(eng.out) == set(want)
+    for k, v in want.items():
+        assert _bits(eng.out[k]) == _bits(v), k
+
+
+def test_run_stages_and_fetches_one_buffer_each_way():
+    """A sweep call puts one buffer on the device and copies one back,
+    and a warm call of the same shape compiles nothing."""
+    from repro import obs
+    from repro.core import sweep_jax
+    lanes = [(_short(duration_h=24.0), s) for s in (0, 1)]
+    for _call in range(2):
+        sweep_jax.run_jax_detailed(lanes)
+        call = obs.calls()[-1]
+        spans = {s.name: s.counters for s in call.spans}
+        assert spans["engine.put"]["h2d_arrays"] == 1
+        assert spans["engine.fetch"]["d2h_arrays"] == 1
+        assert spans["engine.put"]["h2d_bytes"] > 0
+        assert spans["engine.fetch"]["d2h_bytes"] > 0
+    assert call.counters.get("compiles", 0) == 0

@@ -16,7 +16,6 @@ import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -103,18 +102,10 @@ def test_scan_compiles_with_native_kernels(one_chip, monkeypatch, spec):
     here, since this process's backend is the CPU."""
     eng = _engine(spec)
     monkeypatch.setattr(sharding_ctx, "on_tpu", lambda: True)
-
-    def structs(tree):
-        return {k: _struct(one_chip, np.shape(v), np.asarray(v).dtype)
-                for k, v in tree.items()}
-
-    xs = (_struct(one_chip, (eng.N,), jnp.int32),
-          _struct(one_chip, (eng.N,), jnp.int32),
-          _struct(one_chip, (eng.N,), jnp.bool_))
+    (buf,), kw = eng._scan_call()
     compiled = sweep_jax._scan_campaigns.lower(
-        structs(eng.planes), structs(eng.consts), xs,
-        nat_any=eng.nat_any, use_pallas=True,
-        dp_gating=eng.dp_active, dp_staging=eng.dp_staging).compile()
+        _struct(one_chip, buf.shape, buf.dtype),
+        **dict(kw, use_pallas=True)).compile()
     # four tick kernels, each a custom call (preempt runs twice a tick)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 4
