@@ -1,8 +1,9 @@
 """Where JAX keeps compiled programs between runs of an entry point.
 
-Scripts and benchmarks call :func:`use_compile_cache` before their first
-compile; importing the library never does, so tests and library callers
-keep whatever cache policy their process already has.
+Entry points (``chip_smoke.py``, ``benchmarks/chip/bench.py``) call
+:func:`use_compile_cache` before their first compile; importing the
+library never does, so tests and library callers keep whatever cache
+policy their process already has.
 """
 from __future__ import annotations
 

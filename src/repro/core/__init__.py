@@ -12,10 +12,7 @@ overlay      — OSG CE + glideinWMS analogue: pilots, leases, matchmaking
 simulator    — discrete-event cloud simulator binding the above
 events       — typed, replayable CampaignTrace event stream (emitted
                byte-identically by every engine via collect="trace")
-campaign     — deprecated shims (run_campaign/replay_paper_campaign/
-               CampaignController) over specs
-scenarios    — what-if spec library (spot mixes, outages, budgets) +
-               the deprecated Scenario shim
+scenarios    — what-if spec library (spot mixes, outages, budgets)
 sweep        — batched multi-campaign engine: B campaigns, one array program
 elastic      — pod-pool -> mesh manager for synchronous SPMD training (TPU)
 straggler    — speculative re-execution + slow-pod eviction
@@ -24,10 +21,7 @@ The CLI lives one level up: ``python -m repro.campaigns run spec.json``.
 """
 from repro.core.api import run, sweep as run_sweep  # noqa: F401
 from repro.core.budget import BudgetLedger  # noqa: F401
-from repro.core.campaign import (CampaignController, PAPER_RAMP,  # noqa: F401
-                                 replay_paper_campaign, run_campaign,
-                                 sweep_campaigns)
-from repro.core.scenarios import Scenario, default_suite  # noqa: F401
+from repro.core.scenarios import default_suite  # noqa: F401
 from repro.core.spec import (BudgetFloor, CampaignResult,  # noqa: F401
                              CampaignSpec, CapacityShift, CEOutage,
                              PriceCurve, PriceShift, SetTarget,

@@ -31,7 +31,6 @@ tests/test_sweep_jax.py via
 byte-for-byte.  The allowed-engine sets below (:data:`SOLO_ENGINES`,
 :data:`SWEEP_ENGINES`, :data:`ENGINES`) are the single source of truth
 for ``run``/``sweep`` validation and the ``campaigns`` CLI choices.
-The deprecated ``Scenario`` shim is accepted anywhere a spec is.
 """
 from __future__ import annotations
 
@@ -57,8 +56,6 @@ ENGINES = SWEEP_ENGINES | {"auto"}
 #: engines with a per-instance trace surface (``collect="trace"``):
 #: every bit-identical engine; the statistical jax tier is excluded
 TRACE_ENGINES = frozenset(SWEEP_ENGINES - {"jax"})
-
-_SOLO_ENGINES = SOLO_ENGINES          # backwards-compat alias
 
 
 def _no_trace_error() -> ValueError:

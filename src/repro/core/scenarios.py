@@ -13,107 +13,19 @@ price/capacity shifts) — that every execution path understands:
   * batched: ``api.run(specs, seeds=seeds)`` ticks all (spec, seed)
     lanes in lock-step as one array program, bit-reproducible against
     the solo run at the same (seed, spec).
-
-The frozen :class:`Scenario` dataclass is the legacy declaration (ramp/
-outage as dedicated fields rather than a timeline); it remains importable
-as a deprecation-warned shim with a ``to_spec()`` bridge.
 """
 from __future__ import annotations
 
-import warnings
-from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
-from repro.core.campaign import (OUTAGE_AT_H, OUTAGE_DURATION_H, PAPER_RAMP,
-                                 POST_OUTAGE_TARGET, RampStage, _timeline)
-from repro.core.provider import T4_FP32_TFLOPS, ProviderSpec
-from repro.core.simulator import SimConfig
 from repro.core.spec import (CacheFlush, CampaignSpec, CEOutage, DataOrigin,
                              DataPlane, GpuSlicing, OriginDegrade,
                              OriginOutage, PAPER_RAMP_EVENTS, PAPER_TIMELINE,
-                             PriceCurve, WorkloadCurve,
-                             build_catalog as _spec_build_catalog,
-                             paper_spec, run_solo)
+                             PriceCurve, WorkloadCurve, paper_spec)
 
-
-@dataclass(frozen=True)
-class Scenario:
-    """Deprecated: one campaign variant as dedicated ramp/outage fields;
-    defaults reproduce the paper replay.  Use ``CampaignSpec`` (same
-    defaults) with a declarative ``timeline`` instead."""
-    name: str = "paper"
-    catalog: str = "t4"                  # "t4" | "heterogeneous" (§III pool)
-    capacity_scale: float = 1.0          # multiply every region's capacity
-    spot: bool = True                    # spot (paper) vs on-demand pricing
-    ondemand_fraction: float = 0.0       # carve this capacity share into
-    #                                      preemption-free on-demand pools
-    price_scale: float = 1.0             # uniform price-curve perturbation
-    ramp: Tuple[RampStage, ...] = PAPER_RAMP
-    outage: bool = True
-    outage_at_h: float = OUTAGE_AT_H
-    outage_duration_h: float = OUTAGE_DURATION_H
-    resume_target: int = POST_OUTAGE_TARGET
-    budget: float = 58000.0
-    budget_floor_fraction: float = 0.2
-    downscale_target: int = POST_OUTAGE_TARGET
-    duration_h: float = 14 * 24.0
-    dt_h: float = 0.25
-    lease_interval_s: float = 120.0
-    job_wall_h: float = 4.0
-    job_checkpoint_h: float = 1.0
-    min_queue: int = 4000
-    overhead_per_day: float = 390.0
-    accel_tflops: float = T4_FP32_TFLOPS
-
-    def __post_init__(self):
-        warnings.warn(
-            "Scenario is deprecated; declare campaigns as "
-            "repro.core.spec.CampaignSpec (Scenario(...).to_spec() "
-            "bridges existing code)", DeprecationWarning, stacklevel=3)
-
-    def to_spec(self) -> CampaignSpec:
-        """The equivalent declarative spec (ramp/outage fields become
-        timeline events); runs bit-identically on every engine."""
-        return CampaignSpec(
-            name=self.name, catalog=self.catalog,
-            capacity_scale=self.capacity_scale, spot=self.spot,
-            ondemand_fraction=self.ondemand_fraction,
-            price_scale=self.price_scale, budget=self.budget,
-            budget_floor_fraction=self.budget_floor_fraction,
-            downscale_target=self.downscale_target,
-            duration_h=self.duration_h, dt_h=self.dt_h,
-            lease_interval_s=self.lease_interval_s,
-            job_wall_h=self.job_wall_h,
-            job_checkpoint_h=self.job_checkpoint_h,
-            min_queue=self.min_queue,
-            overhead_per_day=self.overhead_per_day,
-            accel_tflops=self.accel_tflops,
-            timeline=_timeline(self.ramp, self.outage,
-                               outage_at_h=self.outage_at_h,
-                               outage_duration_h=self.outage_duration_h,
-                               resume_target=self.resume_target))
-
-
-def build_catalog(sc) -> Dict[str, ProviderSpec]:
-    """Shim: the spec's provider catalog (accepts CampaignSpec or the
-    deprecated Scenario)."""
-    return _spec_build_catalog(sc.to_spec())
-
-
-def sim_config(sc, seed: int) -> SimConfig:
-    """Shim: the spec's engine knobs as a SimConfig."""
-    return SimConfig.from_spec(sc.to_spec(), seed)
-
-
-def run_scenario(sc, seed: int, engine=None):
-    """Deprecated shim: solo reference execution of one (scenario, seed)
-    campaign; returns (results dict, controller).  Use
-    ``api.run(spec, seeds=seed)`` — typed results — instead."""
-    warnings.warn("run_scenario() is deprecated; use "
-                  "repro.core.api.run(spec, seeds=seed)",
-                  DeprecationWarning, stacklevel=2)
-    res, ctl = run_solo(sc.to_spec(), seed, engine=engine)
-    return res.to_dict(), ctl
+# the paper CE outage's resume target (~20 % budget left): every
+# outage-grid member resumes where the paper's did
+POST_OUTAGE_TARGET = PAPER_TIMELINE[-1].resume_target
 
 
 # -- the library (all entries are CampaignSpecs) ---------------------------

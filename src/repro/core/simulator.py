@@ -1,9 +1,9 @@
 """Discrete-event simulator for the cloud pool: preemptions, billing,
 pilots, jobs — deterministic (seeded numpy), hour-granular.
 
-Drives provisioner + overlay + budget together so campaign.py can replay
-the paper's two-week exercise and the benchmarks can compare simulated
-totals (GPU-days, $, EFLOP-hours, preemption counts) against the paper's
+Drives provisioner + overlay + budget together so ``spec.run_solo`` can
+replay the paper's two-week exercise and compare simulated totals
+(GPU-days, $, EFLOP-hours, preemption counts) against the paper's
 published numbers (§IV/§V).
 
 Two interchangeable engines drive the tick:
@@ -11,15 +11,15 @@ Two interchangeable engines drive the tick:
   * ``engine="array"`` (default): the vectorized struct-of-arrays engine
     (core/fleet.py) — instances/pilots/jobs live in parallel numpy arrays
     and every phase of the tick is an array op.  This is what makes
-    100k-instance campaigns tractable (benchmarks/fleet_scale.py).
+    100k-instance campaigns tractable.
   * ``engine="object"``: the seed dataclass engine (one Python object per
     instance/pilot/job).  Kept as the executable specification; the two
     engines consume the RNG identically and produce matching results
     (tests/test_fleet_engine.py).
 
 ``sim.prov`` and ``sim.ce`` expose the same API either way (the array
-engine provides thin dataclass view layers), so campaign.py, the examples
-and the tests are engine-agnostic.
+engine provides thin dataclass view layers), so the timeline controller,
+the examples and the tests are engine-agnostic.
 """
 from __future__ import annotations
 
@@ -55,10 +55,9 @@ class SimConfig:
     @classmethod
     def from_spec(cls, spec, seed: int,
                   engine: Optional[str] = None) -> "SimConfig":
-        """Engine knobs of a ``repro.core.spec.CampaignSpec`` (duck-typed
-        so the deprecated Scenario shim also works).  ``seed`` must be an
-        integer: a float like 3.7 would previously truncate to 3 via
-        ``int()`` and silently run a different campaign, and a bool
+        """Engine knobs of a ``repro.core.spec.CampaignSpec``.  ``seed``
+        must be an integer: a float like 3.7 would previously truncate to
+        3 via ``int()`` and silently run a different campaign, and a bool
         (``True`` is an ``int`` subclass) would silently run seed 1."""
         if isinstance(seed, bool) or not isinstance(
                 seed, (int, np.integer)):

@@ -3,11 +3,9 @@
 The paper's exercise was one hand-driven two-week run; sweep-scale
 planning (HEPCloud-style pre-burst studies, per-scenario cost analyses)
 wants campaign definitions that are *data*: storable, diffable,
-sweepable, replayable in CI.  Historically a campaign's definition was
-smeared across four layers — ``SimConfig``, the frozen ``Scenario``
-dataclass, ``run_campaign()``'s keyword knobs and opaque
-``sim.at(lambda sim: ...)`` callbacks inside ``CampaignController`` — so
-adding one knob touched all four and nothing serialized.
+sweepable, replayable in CI, rather than engine knobs on ``SimConfig``
+and opaque ``sim.at(lambda sim: ...)`` callbacks that nothing can
+serialize.
 
 ``CampaignSpec`` subsumes all of it:
 
@@ -136,7 +134,8 @@ class CampaignSpec:
     dataplane: Optional[DataPlane] = None
 
     def to_spec(self) -> "CampaignSpec":
-        """Duck-typed coercion hook shared with the Scenario shim."""
+        """Identity coercion hook: ``api``/``sweep`` accept any object
+        that returns a CampaignSpec here."""
         return self
 
     def validate(self) -> "CampaignSpec":

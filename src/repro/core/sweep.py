@@ -16,7 +16,7 @@ Reproducibility is exact, not statistical: lane b draws from its own
 (preemption draws per group in price order, creation order within a
 group; ``rng.random(k1); rng.random(k2)`` reads the PCG64 stream exactly
 like ``rng.random(k1+k2)``).  Every lane therefore reports ``results()``
-totals matching a solo ``run_scenario()`` at the same (seed, scenario) —
+totals matching a solo ``spec.run_solo()`` at the same (seed, spec) —
 pinned by tests/test_sweep.py, including the paper replay at seed 2021.
 
 The hot loop never rescans or re-sorts the whole fleet: the engine
@@ -195,7 +195,7 @@ class _LaneOps:
 
 
 def _prepare(sc, seed: int) -> Tuple[tuple, _Lane]:
-    sc = sc.to_spec().validate()      # CampaignSpec or Scenario shim
+    sc = sc.to_spec().validate()      # to_spec(): CampaignSpec's identity hook
     cat = build_catalog(sc)
     pairs = [(p, r) for p in cat.values() for r in p.regions]
     pairs.sort(key=lambda pr: (

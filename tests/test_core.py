@@ -10,12 +10,11 @@ import hypothesis.strategies as st_
 from hypothesis import given, settings
 
 from repro.core.budget import BudgetLedger
-from repro.core.campaign import (ICECUBE_BASELINE_GPUH_PER_2W,
-                                 replay_paper_campaign)
 from repro.core.overlay import ComputeElement, Job
 from repro.core.provider import t4_catalog
 from repro.core.provisioner import MultiCloudProvisioner
 from repro.core.simulator import CloudSimulator, SimConfig
+from repro.core.spec import ICECUBE_BASELINE_GPUH_PER_2W, paper_spec, run_solo
 from repro.core.straggler import SpeculativeScheduler, StragglerMonitor
 
 
@@ -147,7 +146,7 @@ def test_provisioner_bills_ledger():
 # --------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def campaign():
-    return replay_paper_campaign()
+    return run_solo(paper_spec(), 2021)
 
 
 def test_campaign_gpu_days(campaign):

@@ -8,11 +8,11 @@ schedule identity test rides along where hypothesis is installed.
 import numpy as np
 import pytest
 
-from repro.core.campaign import (RampStage, replay_paper_campaign,
-                                 run_campaign)
 from repro.core.overlay import Job
 from repro.core.provider import heterogeneous_catalog, t4_catalog
 from repro.core.simulator import CloudSimulator, SimConfig
+from repro.core.spec import (CampaignSpec, CEOutage, SetTarget, paper_spec,
+                             run_solo)
 from tests.engine_equivalence import assert_results_match
 
 
@@ -27,9 +27,9 @@ def _assert_results_match(a, o):
 def test_paper_replay_engines_identical():
     """The flagship invariant: both engines consume the RNG identically
     and report matching totals for the paper replay at seed 2021."""
-    res_a, ctl_a = replay_paper_campaign(seed=2021, engine="array")
-    res_o, ctl_o = replay_paper_campaign(seed=2021, engine="object")
-    _assert_results_match(res_a, res_o)
+    res_a, ctl_a = run_solo(paper_spec(), 2021, engine="array")
+    res_o, ctl_o = run_solo(paper_spec(), 2021, engine="object")
+    _assert_results_match(res_a.to_dict(), res_o.to_dict())
     # the operational sequence (ramp, outage, budget cap) happens at the
     # same ticks; only within-tick $ snapshots in alert text may differ
     ev_a = [l for l in ctl_a.log if l.startswith("t=")]
@@ -199,24 +199,24 @@ def test_all_instances_includes_compacted():
             for p in rate), rel=1e-9)
 
 
-def test_run_campaign_custom_ramp_and_outage():
-    """run_campaign: custom catalogs/ramps are expressible and the
-    outage + budget-cap machinery works outside the T4 replay."""
-    ramp = (RampStage(0.0, 100), RampStage(4.0, 2000))
-    cfg = SimConfig(duration_h=48.0, seed=6)
-    res, ctl = run_campaign(heterogeneous_catalog(), budget=30000.0,
-                            ramp=ramp, sim_cfg=cfg, outage=True)
+def test_custom_ramp_and_outage_spec():
+    """Custom inline catalogs/ramps are expressible as a CampaignSpec and
+    the outage + budget-cap machinery works outside the T4 replay."""
+    spec = CampaignSpec(
+        name="campaign", providers=tuple(heterogeneous_catalog().values()),
+        budget=30000.0, duration_h=48.0,
+        timeline=(SetTarget(0.0, 100), SetTarget(4.0, 2000),
+                  CEOutage(252.0, 2.0, 1000)))
+    res, ctl = run_solo(spec, 6, engine="array")
+    res = res.to_dict()
     log = "\n".join(ctl.log)
     assert "scale_to(100)" in log and "scale_to(2000)" in log
     assert res["accel_hours"] > 0
     assert res["budget"]["overdraft"] == 0
     assert sum(res["by_provider"].values()) > 0
     # engine parameter honored
-    res_o, _ = run_campaign(heterogeneous_catalog(), budget=30000.0,
-                            ramp=ramp, sim_cfg=SimConfig(
-                                duration_h=48.0, seed=6, engine="object"),
-                            outage=True)
-    _assert_results_match(res, res_o)
+    res_o, _ = run_solo(spec, 6, engine="object")
+    _assert_results_match(res, res_o.to_dict())
 
 
 def test_job_ids_unique_across_requeues():

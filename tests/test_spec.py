@@ -10,23 +10,19 @@ tests/test_spec_properties.py where hypothesis is installed):
   * randomized specs — including the new timed PriceShift / BudgetFloor /
     CapacityShift events — run bit-identically solo vs batched, with
     matching events_fired provenance,
-  * SweepResult.to_csv is deterministic and row-ordered,
-  * the legacy Scenario / run_campaign / replay_paper_campaign shims
-    keep working (deprecation-warned) with unchanged semantics.
+  * SweepResult.to_csv is deterministic and row-ordered.
 """
 import json
 import os
-import warnings
 
 import pytest
 
 from repro.core.api import run, sweep as api_sweep
-from repro.core.campaign import replay_paper_campaign, sweep_campaigns
 from repro.core.provider import t4_catalog
 from repro.core.spec import (BudgetFloor, CampaignResult, CampaignSpec,
                              CapacityShift, CEOutage, GpuSlicing,
-                             PAPER_RAMP_EVENTS, PriceCurve, PriceShift,
-                             SetTarget, paper_spec, run_solo)
+                             PriceCurve, PriceShift, SetTarget, paper_spec,
+                             run_solo)
 from tests.engine_equivalence import (assert_engines_equivalent,
                                       assert_results_match,
                                       assert_sweep_equivalent)
@@ -143,13 +139,6 @@ def test_run_paper_spec_reproduces_pinned_totals(paper_result):
     assert len(res.history) == 336 * 4
 
 
-def test_run_matches_deprecated_replay_shim(paper_result):
-    with pytest.warns(DeprecationWarning):
-        legacy, ctl = replay_paper_campaign(seed=2021)
-    assert paper_result.to_dict() == legacy
-    assert list(paper_result.log) == ctl.log
-
-
 # -- randomized specs: solo == batched, including the new event kinds ------
 
 def _random_specs():
@@ -203,14 +192,14 @@ def test_mixed_spec_sweep_batched_matches_sequential():
         assert row["events_fired"], "provenance must not be empty"
 
 
-def test_sweep_campaigns_sequential_carries_events_fired():
+def test_sequential_sweep_carries_events_fired():
     """Regression (satellite): the sequential engine used to discard the
     per-lane controller provenance; both engines now record it."""
     spec = CampaignSpec(name="tiny", duration_h=24.0, budget=3000.0,
                         timeline=(SetTarget(0.0, 120),
                                   CEOutage(6.0, 2.0, 80)))
     for engine in ("batched", "sequential"):
-        sw = sweep_campaigns([spec], [5], engine=engine)
+        sw = api_sweep([spec], [5], engine=engine)
         (row,) = sw.rows
         kinds = [e["event"] for e in row["events_fired"]]
         assert kinds[:2] == ["scale", "outage_on"], engine
@@ -362,7 +351,7 @@ def test_run_rejects_float_seeds():
     with pytest.raises(TypeError):
         api_sweep([spec, spec], [1.5, 2], engine="batched")
     with pytest.raises(TypeError):
-        sweep_campaigns([spec], [2.5])
+        api_sweep([spec], [2.5])
     # and the SimConfig derivation itself is guarded
     from repro.core.simulator import SimConfig
     with pytest.raises(TypeError):
@@ -371,25 +360,3 @@ def test_run_rejects_float_seeds():
     assert run(spec, seeds=np.int64(5)).seed == 5
 
 
-# -- shims stay importable and equivalent ----------------------------------
-
-def test_scenario_shim_bridges_to_spec():
-    with pytest.warns(DeprecationWarning):
-        from repro.core.scenarios import Scenario
-        sc = Scenario()
-    assert sc.to_spec() == paper_spec()
-    with pytest.warns(DeprecationWarning):
-        custom = Scenario(outage_at_h=60.0, outage_duration_h=12.0)
-    tl = custom.to_spec().timeline
-    assert tl[:-1] == PAPER_RAMP_EVENTS
-    assert tl[-1] == CEOutage(60.0, 12.0, 1000)
-
-
-def test_run_accepts_scenario_shims():
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from repro.core.scenarios import Scenario
-        sc = Scenario(duration_h=24.0, outage=False, budget=5000.0)
-        res = run(sc, seeds=9)
-    solo, _ = run_solo(sc.to_spec(), 9)
-    assert res.to_dict() == solo.to_dict()

@@ -1,6 +1,6 @@
 """Batched sweep engine correctness: every lane of a batched multi-
 campaign sweep must report the same ``results()`` totals as a solo
-``run_scenario()`` at the same (seed, scenario) — including the paper
+``spec.run_solo()`` at the same (seed, spec) — including the paper
 replay at seed 2021 — and money must conserve per lane.  Plus the
 per-engine instance-ID determinism regression (IDs used to come from a
 module-global ``itertools.count``, so they depended on how many
@@ -9,14 +9,14 @@ import numpy as np
 import pytest
 
 from repro.core import sweep
-from repro.core.campaign import replay_paper_campaign, sweep_campaigns
+from repro.core.api import run, sweep as api_sweep
 from repro.core.provider import t4_catalog
 from repro.core.provisioner import MultiCloudProvisioner
-from repro.core.scenarios import (Scenario, budget_floor_variants,
-                                  build_catalog, default_suite,
-                                  outage_grid, run_scenario,
-                                  spot_ondemand_mixes)
+from repro.core.scenarios import (budget_floor_variants, default_suite,
+                                  outage_grid, spot_ondemand_mixes)
 from repro.core.simulator import CloudSimulator, SimConfig
+from repro.core.spec import (CampaignSpec, CEOutage, PAPER_RAMP_EVENTS,
+                             build_catalog, paper_spec, run_solo)
 from tests.engine_equivalence import assert_results_match
 
 # migrated call sites keep the historical underscore name
@@ -26,17 +26,17 @@ _assert_results_match = assert_results_match
 def test_sweep_lanes_match_solo_campaigns():
     """The flagship sweep invariant, pinned on three lanes including the
     full paper replay at seed 2021: batched lane totals == solo run."""
-    lanes = [(Scenario(), 2021), (Scenario(), 7),
+    lanes = [(CampaignSpec(), 2021), (CampaignSpec(), 7),
              (outage_grid((60.0,), (12.0,))[0], 7)]
-    sw = sweep_campaigns([Scenario(), outage_grid((60.0,), (12.0,))[0]],
-                         [2021, 7])
+    sw = api_sweep([CampaignSpec(), outage_grid((60.0,), (12.0,))[0]],
+                   [2021, 7], engine="batched")
     by_key = {(r["scenario"], r["seed"]): r for r in sw.rows}
     for sc, seed in lanes:
-        solo, _ = run_scenario(sc, seed)
-        _assert_results_match(by_key[(sc.name, seed)], solo)
-    # and the seed-2021 paper lane reproduces the replay helper's totals
-    replay, _ = replay_paper_campaign(seed=2021)
-    _assert_results_match(by_key[("paper", 2021)], replay)
+        solo, _ = run_solo(sc, seed)
+        _assert_results_match(by_key[(sc.name, seed)], solo.to_dict())
+    # and the seed-2021 paper lane reproduces the front door's replay
+    replay = run(paper_spec(), seeds=2021)
+    _assert_results_match(by_key[("paper", 2021)], replay.to_dict())
     # ... which are the paper's numbers
     paper_lane = by_key[("paper", 2021)]
     assert 14500 <= paper_lane["accel_days"] <= 17500
@@ -58,7 +58,8 @@ def test_instance_ids_deterministic_per_engine():
         assert sorted(i.id for i in prov.live_instances()) \
             == list(range(50))
     # batched: every lane numbers its own instances from 0
-    lanes = [sweep._prepare(Scenario(duration_h=2.0), s)[1] for s in (1, 2)]
+    lanes = [sweep._prepare(CampaignSpec(duration_h=2.0), s)[1]
+             for s in (1, 2)]
     eng = sweep.BatchedFleetEngine(lanes).run()
     for b in range(eng.B):
         lane_rows = (eng.i_lg[:eng.n] // eng.G) == b
@@ -70,7 +71,8 @@ def test_instance_ids_deterministic_per_engine():
 def test_batched_money_conservation():
     """Per lane: charged $ == billed instance-hours x group rate
     (+ infra overhead), including compacted-away instances."""
-    sc = Scenario(duration_h=72.0, outage=False, budget=1e9)
+    sc = CampaignSpec(duration_h=72.0, timeline=PAPER_RAMP_EVENTS,
+                      budget=1e9)
     lanes = [sweep._prepare(sc, s)[1] for s in (5, 6)]
     eng = sweep.BatchedFleetEngine(lanes).run()
     hours = eng.billed_hours_by_lg()
@@ -84,15 +86,15 @@ def test_batched_money_conservation():
 
 
 def test_sequential_engine_matches_batched():
-    """sweep_campaigns(engine='sequential') is the reference loop; the
+    """api.sweep(engine='sequential') is the reference loop; the
     batched engine must agree row by row."""
-    scs = [Scenario(duration_h=36.0), Scenario(name="early-outage",
-                                               duration_h=36.0,
-                                               outage_at_h=12.0,
-                                               outage_duration_h=4.0)]
+    scs = [CampaignSpec(duration_h=36.0),
+           CampaignSpec(name="early-outage", duration_h=36.0,
+                        timeline=PAPER_RAMP_EVENTS
+                        + (CEOutage(12.0, 4.0, 1000),))]
     seeds = [1, 9]
-    batched = sweep_campaigns(scs, seeds, engine="batched")
-    seq = sweep_campaigns(scs, seeds, engine="sequential")
+    batched = api_sweep(scs, seeds, engine="batched")
+    seq = api_sweep(scs, seeds, engine="sequential")
     assert [r["scenario"] for r in batched.rows] \
         == [r["scenario"] for r in seq.rows]
     for rb, rs in zip(batched.rows, seq.rows):
@@ -100,7 +102,8 @@ def test_sequential_engine_matches_batched():
 
 
 def test_sweep_summary_bands():
-    sw = sweep_campaigns([Scenario(duration_h=48.0)], [1, 2, 3])
+    sw = api_sweep([CampaignSpec(duration_h=48.0)], [1, 2, 3],
+                   engine="batched")
     assert len(sw.rows) == 3
     summ = sw.summary()
     assert set(summ) == {"paper"}
@@ -125,7 +128,7 @@ def test_scenario_library():
     assert od.spot_price_per_day == cat["azure"].ondemand_price_per_day
     assert all(r.preempt_rate_per_hour == 0.0 for r in od.regions)
     # price perturbation scales both price axes
-    pp = build_catalog(Scenario(price_scale=2.0))
+    pp = build_catalog(CampaignSpec(price_scale=2.0))
     base = t4_catalog()
     assert pp["azure"].spot_price_per_day \
         == pytest.approx(2.0 * base["azure"].spot_price_per_day)
@@ -138,10 +141,11 @@ def test_scenario_library():
 def test_ondemand_costs_more_per_gpu_day():
     """Same ramp, same seed: the on-demand lane pays a much higher
     $/GPU-day and sees zero spot preemptions."""
-    sw = sweep_campaigns([Scenario(duration_h=48.0, outage=False,
-                                   budget=1e9),
-                          Scenario(name="od", spot=False, duration_h=48.0,
-                                   outage=False, budget=1e9)], [4])
+    sw = api_sweep([CampaignSpec(duration_h=48.0, budget=1e9,
+                                 timeline=PAPER_RAMP_EVENTS),
+                    CampaignSpec(name="od", spot=False, duration_h=48.0,
+                                 budget=1e9, timeline=PAPER_RAMP_EVENTS)],
+                   [4], engine="batched")
     spot_row, od_row = sw.rows
     assert od_row["cost_per_accel_day"] \
         > 2.0 * spot_row["cost_per_accel_day"]

@@ -26,8 +26,8 @@ def _run_py(code, devices=8, timeout=420):
 def test_paper_campaign_end_to_end():
     """The flagship reproduction: all paper claims in one run (fast: pure
     python simulation)."""
-    from repro.core.campaign import replay_paper_campaign
-    res, ctl = replay_paper_campaign()
+    from repro.core.spec import paper_spec, run_solo
+    res, ctl = run_solo(paper_spec(), 2021)
     assert 14500 <= res["accel_days"] <= 17500
     assert 52000 <= res["cost"] <= 60000
     assert 2.7 <= res["eflop_hours_fp32"] <= 3.4

@@ -28,7 +28,7 @@ import pytest
 from repro.campaigns import main as campaigns_main
 from repro.core.api import run
 from repro.core.events import CampaignTrace, event_to_dict
-from repro.core.spec import CampaignSpec, run_solo
+from repro.core.spec import CampaignSpec, SetTarget, run_solo
 from repro.core.traceops import (CallbackSink, JsonlStreamSink,
                                  StreamingRecorder, TraceDigest,
                                  diff_traces, load_trace, trace_digest)
@@ -283,14 +283,47 @@ def _random_mutation(rng, trace):
     return _mutate(trace, evs), i
 
 
+def _first_difference(a, b):
+    """The first index where two event lists differ; a length mismatch
+    differs at the shorter list's end.  None when they are equal."""
+    n = min(len(a), len(b))
+    for k in range(n):
+        if a[k] != b[k]:
+            return k
+    return None if len(a) == len(b) else n
+
+
 def _check_mutation_detected(trace, mutated, i):
+    # a mutation at i keeps the prefix before i; it can leave i itself
+    # unchanged too, when the dropped event equals its neighbour
+    k = _first_difference(trace.events, mutated.events)
+    assert k is not None and k >= i
     d = diff_traces(trace, mutated)
     assert not d.identical
     assert d.divergence is not None
-    assert d.divergence.index <= i
+    assert d.divergence.index == k
     # the reported first-divergence time is the mutated position's
     # canonical time (or earlier, when the reorder bubbles it up)
     assert d.divergence.t <= max(ev.t for ev in trace.events)
+
+
+def test_diff_drop_of_duplicate_event_diverges_after_it():
+    """Two equal scale events at t=0: dropping the first leaves index 0
+    unchanged, so the first divergence is the length mismatch at 1."""
+    spec = CampaignSpec(name="a", capacity_scale=0.5, spot=False,
+                        price_scale=0.8, budget=2000.0,
+                        budget_floor_fraction=0.1, downscale_target=0,
+                        duration_h=12.0, job_wall_h=1.0, min_queue=500,
+                        timeline=(SetTarget(0.0, 0), SetTarget(0.0, 0)))
+    trace = run_solo(spec, 0, collect="trace")[0].trace
+    assert len(trace.events) == 2
+    assert trace.events[0] == trace.events[1]
+    mutated, i = _random_mutation(random.Random(1), trace)
+    assert i == 0 and len(mutated.events) == 1
+    d = diff_traces(trace, mutated)
+    assert d.divergence.index == 1
+    assert d.divergence.b is None
+    _check_mutation_detected(trace, mutated, i)
 
 
 def test_diff_seeded_fuzz_identity_and_mutations(small_trace):
