@@ -54,6 +54,7 @@ the other engines' schema exactly.
 """
 from __future__ import annotations
 
+import copy
 import functools
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -718,6 +719,15 @@ def _distinct(specs: Sequence) -> Tuple[List[int], List[int]]:
     return u_of, firsts
 
 
+#: the scan outputs a lane's results dict is read from
+_RESULT_FIELDS = ("busy_prov", "busy_h", "spent", "by_prov", "egress_g",
+                  "infra", "live_g", "accel", "pre_ct", "nat_ct", "fin_ct",
+                  "stage_t", "hits", "misses")
+
+#: record values that a shallow copy of a provenance record copies
+_ATOMS = (str, int, float, bool, type(None))
+
+
 class JaxSweepEngine:
     """One lock-step batch of lanes compiled to a single scan (the JAX
     analogue of ``BatchedFleetEngine`` — same batching key, so the two
@@ -774,6 +784,7 @@ class JaxSweepEngine:
         reps = [self.lanes[i] for i in firsts]
         U = len(reps)
         u_of_b = np.array(u_of, np.int64)
+        self._row = u_of
 
         def at_lanes(a: np.ndarray, axis: int) -> np.ndarray:
             return a if U == B else np.take(a, u_of_b, axis=axis)
@@ -990,6 +1001,11 @@ class JaxSweepEngine:
         assert (self.consts["budget"] > 0).all(), \
             "sweep lanes need a budget"
         self.out: Optional[dict] = None
+        # read-back state: the output columns and cap ticks of the last
+        # run, and the replayed records by (spec row, cap tick)
+        self._cols: Optional[Dict[str, list]] = None
+        self._cap_ticks: Optional[List[int]] = None
+        self._replays: Dict[Tuple[int, int], Tuple[List[dict], bool]] = {}
 
     def _scan_call(self):
         """The scan's one argument, packed and put on the device, and
@@ -1026,20 +1042,41 @@ class JaxSweepEngine:
             buf = np.asarray(out)
             layout = _out_layout(*_out_sizes(self.consts))
             self.out = _unpack(buf, layout)["out"]
+            self._cols = self._cap_ticks = None
             obs.count("d2h_bytes", buf.nbytes)
             obs.count("d2h_arrays", 1)
         return self
 
     # -- per-lane provenance + results ------------------------------------
     def lane_events(self, b: int) -> List[dict]:
-        """Reconstruct the lane's ``events_fired`` records through the
-        registry's own ``apply_op`` bodies (schema-identical to the solo
-        and batched engines; the budget cap is inserted at the tick the
-        scan applied it)."""
+        """The lane's ``events_fired`` records, schema-identical to the
+        solo and batched engines'.  They are a function of the lane's
+        spec and of the tick at which the scan applied its budget cap
+        alone, so the registry replay (:meth:`_replay`) runs once per
+        distinct (spec row, cap tick) and each lane gets copies of the
+        records that no other lane shares.  Counts ``replay_lanes`` and
+        ``replay_keys`` (the replays run) on the open span."""
+        if self._cap_ticks is None:
+            self._cap_ticks = self.out["cap_tick"].tolist() \
+                if self.out is not None else [-1] * self.B
+        key = (self._row[b], self._cap_ticks[b])
+        hit = self._replays.get(key)
+        if hit is None:
+            recs = self._replay(b, key[1])
+            flat = all(isinstance(v, _ATOMS) for r in recs
+                       for v in r.values())
+            hit = self._replays[key] = (recs, flat)
+            obs.count("replay_keys")
+        obs.count("replay_lanes")
+        recs, flat = hit
+        return [dict(r) for r in recs] if flat else copy.deepcopy(recs)
+
+    def _replay(self, b: int, cap_tick: int) -> List[dict]:
+        """Reconstruct lane ``b``'s ``events_fired`` records through the
+        registry's own ``apply_op`` bodies (the budget cap is inserted at
+        ``cap_tick``, the tick the scan applied it; -1 if never)."""
         ln = self.lanes[b]
         ops = JaxLaneOps(ln.spec, ln.pairs)
-        cap_tick = int(self.out["cap_tick"][b]) if self.out is not None \
-            else -1
         by_tick: Dict[int, list] = {}
         for (t, kind, arg), ft in zip(self._evs[b], self._fts[b]):
             if ft < self.N:
@@ -1057,73 +1094,84 @@ class JaxSweepEngine:
                                                        now))
         return recs
 
+    def _columns(self) -> Dict[str, list]:
+        """The outputs :meth:`lane_results` reads, as Python lists, one
+        ``tolist`` a field a run: float32 numbers become the Python
+        floats, and int32 counts the ints, that reading them element by
+        element gives.  Beside them: each lane's float32 row sum of
+        ``egress_g``, whether any of its groups has egress, and its live
+        instances summed per provider (exact integer sums)."""
+        if self._cols is None:
+            out = self.out
+            assert out is not None, "run() first"
+            cols = {k: out[k].tolist() for k in _RESULT_FIELDS}
+            cols["budget"] = self.consts["budget"].tolist()
+            cols["egress_sum"] = out["egress_g"].sum(axis=1).tolist()
+            cols["egress_any"] = (out["egress_g"] > 0).any(axis=1).tolist()
+            cols["live_p"] = (out["live_g"].astype(np.int64) @ self.consts[
+                "prov_onehot"].astype(np.int64)).tolist()
+            self._cols = cols
+        return self._cols
+
     def lane_results(self, b: int) -> dict:
         """Summary totals, schema-identical to the other engines'
         ``results()`` (same keys, grouping and rounding)."""
-        out = self.out
-        assert out is not None, "run() first"
+        c = self._columns()
         sc = self.lanes[b].spec
-        busy_by_prov = {}
-        for pidx, name in enumerate(self.providers):
-            h = float(out["busy_prov"][b, pidx])
-            if h > 0:
-                busy_by_prov[name] = h
+        providers = self.providers
+        busy_by_prov = {name: h for name, h in zip(providers,
+                                                    c["busy_prov"][b])
+                        if h > 0}
+        busy_h = c["busy_h"][b]
         if self.homogeneous:
-            eflop = float(out["busy_h"][b]) * sc.accel_tflops * 1e12 / 1e18
+            eflop = busy_h * sc.accel_tflops * 1e12 / 1e18
         else:
             eflop = sum(
                 h * (self.provider_tflops.get(name) or sc.accel_tflops)
                 for name, h in busy_by_prov.items()) * 1e12 / 1e18
-        spent = float(out["spent"][b])
-        budget = float(self.consts["budget"][b])
-        raw_by_prov: Dict[str, float] = {}
-        for pidx, name in enumerate(self.providers):
-            v = float(out["by_prov"][b, pidx])
-            if v > 0:
-                raw_by_prov[name] = v
+        spent = c["spent"][b]
+        budget = c["budget"][b]
+        raw_by_prov = {name: v for name, v in zip(providers, c["by_prov"][b])
+                       if v > 0}
         # egress lands under the BASE provider name, merged before
         # rounding (same grouping as the other engines' ledgers)
-        for g, base in enumerate(self.dp_base_g):
-            e = float(out["egress_g"][b, g])
-            if e > 0:
-                raw_by_prov[base] = raw_by_prov.get(base, 0.0) + e
+        if c["egress_any"][b]:
+            for base, e in zip(self.dp_base_g, c["egress_g"][b]):
+                if e > 0:
+                    raw_by_prov[base] = raw_by_prov.get(base, 0.0) + e
         ledger_by_prov = {k: round(v, 2) for k, v in raw_by_prov.items()}
-        infra = float(out["infra"][b])
+        infra = c["infra"][b]
         if infra > 0:
             ledger_by_prov["infra"] = round(infra, 2)
-        by_provider: Dict[str, int] = {}
-        for g, name in enumerate(self.g_provider):
-            by_provider[name] = by_provider.get(name, 0) \
-                + int(out["live_g"][b, g])
-        accel = float(out["accel"][b])
+        accel = c["accel"][b]
+        accel_days = accel / 24.0
+        cost = round(spent, 2)
+        remaining = max(0.0, budget - spent)
+        hits, misses = c["hits"][b], c["misses"][b]
         return {
             "accel_hours": round(accel, 1),
-            "accel_days": round(accel / 24.0, 1),
-            "busy_hours": round(float(out["busy_h"][b]), 1),
+            "accel_days": round(accel_days, 1),
+            "busy_hours": round(busy_h, 1),
             "busy_hours_by_provider": {
                 k: round(v, 1) for k, v in sorted(busy_by_prov.items())},
             "eflop_hours_fp32": round(eflop, 3),
-            "cost": round(spent, 2),
-            "cost_per_accel_day": round(
-                spent / max(accel / 24.0, 1e-9), 2),
-            "preemptions": int(out["pre_ct"][b]),
-            "nat_drops": int(out["nat_ct"][b]),
-            "jobs_finished": int(out["fin_ct"][b]),
-            "egress_usd": round(float(out["egress_g"][b].sum()), 2),
-            "stagein_hours": round(float(out["stage_t"][b]) * self.dt, 1),
-            "cache_hit_fraction": round(
-                float(out["hits"][b])
-                / (float(out["hits"][b]) + float(out["misses"][b])), 4)
-            if float(out["hits"][b]) + float(out["misses"][b]) else 0.0,
+            "cost": cost,
+            "cost_per_accel_day": round(spent / max(accel_days, 1e-9), 2),
+            "preemptions": c["pre_ct"][b],
+            "nat_drops": c["nat_ct"][b],
+            "jobs_finished": c["fin_ct"][b],
+            "egress_usd": round(c["egress_sum"][b], 2),
+            "stagein_hours": round(c["stage_t"][b] * self.dt, 1),
+            "cache_hit_fraction": round(hits / (hits + misses), 4)
+            if hits + misses else 0.0,
             "budget": {
-                "total_spent": round(spent, 2),
+                "total_spent": cost,
                 "by_provider": dict(sorted(ledger_by_prov.items())),
-                "remaining": round(max(0.0, budget - spent), 2),
-                "remaining_fraction": round(
-                    max(0.0, budget - spent) / budget, 4),
+                "remaining": round(remaining, 2),
+                "remaining_fraction": round(remaining / budget, 4),
                 "overdraft": round(max(0.0, spent - budget), 2),
             },
-            "by_provider": by_provider,
+            "by_provider": dict(zip(providers, c["live_p"][b])),
         }
 
 

@@ -513,6 +513,235 @@ def test_run_jax_prepares_once_per_spec_and_keeps_input_order(monkeypatch):
     assert got[0] == got[5] and got[0][0] != got[2][0]
 
 
+# -- readback: one replay per (spec, cap tick), results from columns ---------
+
+def _events_per_lane(eng, b):
+    """The provenance replay as it was done lane by lane before it was
+    shared per (spec, cap tick): the oracle for ``lane_events``."""
+    from repro.core import timeline as timeline_registry
+    from repro.core.sweep_jax import JaxLaneOps
+    ln = eng.lanes[b]
+    ops = JaxLaneOps(ln.spec, ln.pairs)
+    cap_tick = int(eng.out["cap_tick"][b]) if eng.out is not None else -1
+    by_tick = {}
+    for (t, kind, arg), ft in zip(eng._evs[b], eng._fts[b]):
+        if ft < eng.N:
+            by_tick.setdefault(int(ft), []).append((kind, arg))
+    ticks = sorted(set(by_tick) | ({cap_tick} if cap_tick >= 0 else set()))
+    recs = []
+    for ft in ticks:
+        now = float(eng.tick_times[ft])
+        ops.budget_capped = 0 <= cap_tick <= ft
+        if ft == cap_tick:
+            recs.append(timeline_registry.apply_budget_cap(ops, now))
+        for kind, arg in by_tick.get(ft, []):
+            recs.append(timeline_registry.apply_op(ops, kind, arg, now))
+    return recs
+
+
+def _results_per_lane(eng, b):
+    """``lane_results`` as it was read element by element before it
+    read columns: the oracle for the column-wise body."""
+    out = eng.out
+    sc = eng.lanes[b].spec
+    busy_by_prov = {}
+    for pidx, name in enumerate(eng.providers):
+        h = float(out["busy_prov"][b, pidx])
+        if h > 0:
+            busy_by_prov[name] = h
+    if eng.homogeneous:
+        eflop = float(out["busy_h"][b]) * sc.accel_tflops * 1e12 / 1e18
+    else:
+        eflop = sum(
+            h * (eng.provider_tflops.get(name) or sc.accel_tflops)
+            for name, h in busy_by_prov.items()) * 1e12 / 1e18
+    spent = float(out["spent"][b])
+    budget = float(eng.consts["budget"][b])
+    raw_by_prov = {}
+    for pidx, name in enumerate(eng.providers):
+        v = float(out["by_prov"][b, pidx])
+        if v > 0:
+            raw_by_prov[name] = v
+    for g, base in enumerate(eng.dp_base_g):
+        e = float(out["egress_g"][b, g])
+        if e > 0:
+            raw_by_prov[base] = raw_by_prov.get(base, 0.0) + e
+    ledger_by_prov = {k: round(v, 2) for k, v in raw_by_prov.items()}
+    infra = float(out["infra"][b])
+    if infra > 0:
+        ledger_by_prov["infra"] = round(infra, 2)
+    by_provider = {}
+    for g, name in enumerate(eng.g_provider):
+        by_provider[name] = by_provider.get(name, 0) \
+            + int(out["live_g"][b, g])
+    accel = float(out["accel"][b])
+    hits, misses = float(out["hits"][b]), float(out["misses"][b])
+    return {
+        "accel_hours": round(accel, 1),
+        "accel_days": round(accel / 24.0, 1),
+        "busy_hours": round(float(out["busy_h"][b]), 1),
+        "busy_hours_by_provider": {
+            k: round(v, 1) for k, v in sorted(busy_by_prov.items())},
+        "eflop_hours_fp32": round(eflop, 3),
+        "cost": round(spent, 2),
+        "cost_per_accel_day": round(spent / max(accel / 24.0, 1e-9), 2),
+        "preemptions": int(out["pre_ct"][b]),
+        "nat_drops": int(out["nat_ct"][b]),
+        "jobs_finished": int(out["fin_ct"][b]),
+        "egress_usd": round(float(out["egress_g"][b].sum()), 2),
+        "stagein_hours": round(float(out["stage_t"][b]) * eng.dt, 1),
+        "cache_hit_fraction": round(hits / (hits + misses), 4)
+        if hits + misses else 0.0,
+        "budget": {
+            "total_spent": round(spent, 2),
+            "by_provider": dict(sorted(ledger_by_prov.items())),
+            "remaining": round(max(0.0, budget - spent), 2),
+            "remaining_fraction": round(
+                max(0.0, budget - spent) / budget, 4),
+            "overdraft": round(max(0.0, spent - budget), 2),
+        },
+        "by_provider": by_provider,
+    }
+
+
+def _capped_engine(lane_specs):
+    """A run engine over ``lane_specs`` and a second engine over the same
+    lanes holding the first one's outputs with the cap ticks replaced:
+    lane by lane, never (-1), at tick 0, at each spec's event ticks and
+    past its last event.  A spec's lanes take three cap ticks in turn,
+    one of them -1, so they differ and recur, and equal cap ticks recur
+    under different specs."""
+    import numpy as np
+
+    from repro.core.sweep_jax import JaxSweepEngine, _distinct
+    lanes = [_prepare(sc, seed)[1] for sc, seed in lane_specs]
+    ran = JaxSweepEngine(lanes, use_pallas=False).run()
+    eng = JaxSweepEngine(lanes, use_pallas=False)
+    rows, _firsts = _distinct([ln.spec for ln in lanes])
+    seen = {}
+    caps = []
+    for b, u in enumerate(rows):
+        k = seen[u] = seen.get(u, -1) + 1
+        fts = sorted({int(t) for t in eng._fts[b] if t < eng.N})
+        ticks = [0, *fts[1::2], min(fts[-1] + 3, eng.N - 1)]
+        caps.append(-1 if k % 3 == 0 else ticks[(u + k % 3) % len(ticks)])
+    eng.out = dict(ran.out, cap_tick=np.array(caps, np.int32))
+    return ran, eng
+
+
+def _replay_keys(eng):
+    from repro.core.sweep_jax import _distinct
+    rows, _firsts = _distinct([ln.spec for ln in eng.lanes])
+    return {(u, int(t)) for u, t in zip(rows, eng.out["cap_tick"])}
+
+
+@pytest.mark.parametrize("mix", ["paper", "dataplane"])
+def test_memoised_events_equal_the_per_lane_replay(mix):
+    """``lane_events`` gives every lane the records of its own replay,
+    in input order: the scan's cap ticks, then cap ticks that differ
+    between lanes of one spec (never, tick 0, at and between event
+    ticks, after the last), equal specs as one object and as JSON
+    copies, seeds shared across specs; ``engine.events`` counts the
+    lanes and the distinct (spec, cap tick) keys it replayed."""
+    from repro import obs
+    specs = _paper_mix() if mix == "paper" else _dataplane_mix()
+    ran, eng = _capped_engine(_mixed_lanes(specs) * 2)
+    for e in (ran, eng):
+        with obs.call("test.readback"), obs.span("engine.events"):
+            got = [e.lane_events(b) for b in range(e.B)]
+        assert got == [_events_per_lane(e, b) for b in range(e.B)]
+        counters = obs.calls()[-1].spans[-1].counters
+        assert counters["replay_lanes"] == e.B
+        assert counters["replay_keys"] == len(_replay_keys(e))
+    # the keys engage: lanes of one spec differ in cap tick, and the
+    # cache still replays far fewer keys than lanes
+    by_spec = {}
+    for ln, t in zip(eng.lanes, eng.out["cap_tick"]):
+        by_spec.setdefault(ln.spec, set()).add(int(t))
+    assert all(len(ts) > 1 and -1 in ts for ts in by_spec.values())
+    assert len(_replay_keys(eng)) < eng.B
+    kinds = {r["event"] for recs in got for r in recs}
+    assert "budget_floor" in kinds
+    if mix == "dataplane":
+        assert {"origin_outage_on", "origin_degrade", "cache_flush"} <= kinds
+
+
+def test_memoised_events_are_copies_no_lane_shares():
+    """Changing one lane's records, or a nested value in them, leaves
+    another lane's records and the cache as they were."""
+    from repro.core.sweep_jax import JaxSweepEngine
+    sc = _short(duration_h=72.0)
+    eng = JaxSweepEngine([_prepare(sc, s)[1] for s in (0, 1, 2)],
+                         use_pallas=False).run()
+    want = _events_per_lane(eng, 1)
+    assert want and eng.out["cap_tick"][0] == eng.out["cap_tick"][1]
+    first = eng.lane_events(0)
+    assert first == want
+    first[0]["t"] = -1.0
+    first.append({"event": "planted"})
+    second = eng.lane_events(1)
+    assert second == want and eng.lane_events(0) == want
+    assert all(a is not b for a, b in zip(first, second))
+
+    nested = [{"t": 0.0, "event": "nested", "targets": [1, 2]}]
+    eng._replays.clear()
+    eng._replay = lambda b, cap_tick: [dict(r, targets=list(r["targets"]))
+                                       for r in nested]
+    got = eng.lane_events(0)
+    got[0]["targets"].append(3)
+    assert eng.lane_events(2) == nested and eng.lane_events(0) == nested
+
+
+def test_run_jax_counts_replays_on_engine_events():
+    """A sweep call's ``engine.events`` span carries ``replay_lanes``
+    (every lane) and ``replay_keys`` (one a distinct spec and cap tick)
+    beside ``engine.bake``'s ``bake_lanes`` / ``bake_specs``."""
+    from repro import obs
+    from repro.core import sweep_jax
+    specs = _paper_mix()[:4]
+    lane_specs = [(sc, seed) for sc in specs for seed in (0, 1, 2)]
+    sweep_jax.run_jax_detailed(lane_specs)
+    spans = {s.name: s.counters for s in obs.calls()[-1].spans}
+    eng = sweep_jax.JaxSweepEngine(
+        [_prepare(sc, seed)[1] for sc, seed in lane_specs]).run()
+    assert spans["engine.bake"]["bake_specs"] == 4
+    assert spans["engine.events"]["replay_lanes"] == 12
+    assert spans["engine.events"]["replay_keys"] == len(_replay_keys(eng))
+    assert 4 <= spans["engine.events"]["replay_keys"] < 12
+
+
+@pytest.mark.parametrize("variant", ["plain", "dataplane", "hetero", "nat"])
+def test_column_results_equal_the_per_lane_read(variant):
+    """``lane_results`` read from columns gives each lane's dict of the
+    element-by-element read, byte for byte as JSON, with its keys sorted
+    and in their own order: the paper spec, the data plane (egress,
+    cache hits and misses, stage-in time), providers with their own
+    TFLOPS (``homogeneous`` False) and a lease over Azure's NAT
+    timeout."""
+    import json
+
+    from repro.core import sweep_jax
+    from repro.core.spec import CampaignSpec
+    spec = {"plain": CampaignSpec(),
+            "dataplane": scenarios.dataplane_burst(),
+            "hetero": scenarios.heterogeneous_burst(),
+            "nat": CampaignSpec(lease_interval_s=300.0)}[variant]
+    eng = sweep_jax.JaxSweepEngine(
+        [_prepare(spec, s)[1] for s in (0, 3, 2 ** 31 - 1)],
+        use_pallas=False).run()
+    got = [eng.lane_results(b) for b in range(eng.B)]
+    want = [_results_per_lane(eng, b) for b in range(eng.B)]
+    for g, w in zip(got, want):
+        assert json.dumps(g, sort_keys=True) == json.dumps(w, sort_keys=True)
+        assert json.dumps(g) == json.dumps(w), "key order"
+    assert eng.homogeneous == (variant != "hetero")
+    if variant == "dataplane":
+        assert all(r["egress_usd"] > 0 and r["stagein_hours"] > 0
+                   and 0 < r["cache_hit_fraction"] < 1 for r in got)
+    if variant == "nat":
+        assert any(r["nat_drops"] > 0 for r in got)
+
+
 # -- staging: one packed buffer each way -----------------------------------
 
 def _bits(a):
