@@ -96,7 +96,8 @@ def cell_shape(config: dict, lanes: int) -> dict:
     ln = Lane(config["spec"], 0, config["catalog_tables"])
     return {"B": lanes, "G": ln.G, "W": ln.W, "L": ln.L,
             "P": len(ln.providers), "N": ln.N,
-            "dp_gating": ln.dp_gating, "dp_staging": ln.dp_staging}
+            "dp_gating": ln.dp_gating, "dp_staging": ln.dp_staging,
+            "nat_any": ln.nat_any}
 
 
 class Cell:

@@ -6,11 +6,11 @@ among every lane that the window's calls returned.  The plain reference
 and three numbers compare the rows:
 
 * ``lanes_off``: the share of drawn lanes whose row differs from the
-  reference's anywhere: a count (jobs, preemptions, live instances per
-  provider), the ``events_fired`` provenance, or a float total by more
-  than ``FLOAT_RTOL``;
+  reference's anywhere: a count (jobs, preemptions, NAT drops, live
+  instances per provider), the ``events_fired`` provenance, or a float
+  total by more than ``FLOAT_RTOL``;
 * ``worst_gap``: the widest relative gap of one lane's cost, GPU-days,
-  jobs, preemptions or egress from the reference's;
+  jobs, preemptions, NAT drops or egress from the reference's;
 * ``mean_gap``: the widest relative gap of the same totals' means over
   the drawn lanes.
 
@@ -25,11 +25,11 @@ import numpy as np
 
 from benchmarks.chip import reference
 
-COUNT_KEYS = ("jobs_finished", "preemptions")
+COUNT_KEYS = ("jobs_finished", "preemptions", "nat_drops")
 FLOAT_KEYS = ("cost", "accel_days", "busy_hours", "egress_usd",
               "stagein_hours", "cache_hit_fraction")
 GAP_KEYS = ("cost", "accel_days", "jobs_finished", "preemptions",
-            "egress_usd")
+            "nat_drops", "egress_usd")
 ROW_KEYS = COUNT_KEYS + FLOAT_KEYS + ("by_provider", "events_fired")
 FLOAT_RTOL = 1e-4
 NUMBERS = ("lanes_off", "worst_gap", "mean_gap")

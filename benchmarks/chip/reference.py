@@ -11,10 +11,13 @@ made.  The semantics are those the compiled engine states for itself
 (seed, tick), split over occupancy cells by systematic proportional
 rounding, the queue->pilot matcher split the same way, jobs advancing
 one dt step per tick, billing of the live set at the tick's start, the
-budget-floor tripwire applied at the next tick, and the data plane's
-stage-in as a front extension of the progress axis.  Where the engine
-bakes per-segment planes, this steps each lane's own events at their
-fire ticks; the two are the same campaign.
+budget-floor tripwire applied at the next tick, NAT drops (every busy
+pilot in a group whose NAT idle timeout the lease interval reaches loses
+its job to the queue after the match, and its instance stays alive and
+billed with a dead pilot), and the data plane's stage-in as a front
+extension of the progress axis.  Where the engine bakes per-segment
+planes, this steps each lane's own events at their fire ticks; the two
+are the same campaign.
 
 ``dtype`` is the precision of every float the tick computes: float32,
 the engine's stated precision, or a lower one for the control.  Counts
@@ -111,8 +114,10 @@ class Lane:
         self.spec, self.seed = spec, int(seed)
         gr = _groups(spec, tables)
         G = self.G = len(gr)
-        if any(spec["lease_interval_s"] >= g["nat"] for g in gr):
-            raise ValueError("reference: NAT-dropping groups not supported")
+        # groups whose NAT drops the pilots' lease renewals
+        self.nat_g = np.array([spec["lease_interval_s"] >= g["nat"]
+                               for g in gr], np.int64)
+        self.nat_any = bool(self.nat_g.any())
         self.g_provider = [g["provider"] for g in gr]
         self.providers = list(dict.fromkeys(self.g_provider))
         self.prov_of_g = np.array([self.providers.index(n)
@@ -226,8 +231,8 @@ class Lane:
 
     def shape_key(self) -> tuple:
         return (self.G, self.N, self.W, self.L, self.S_max, self.dt,
-                self.dp_gating, self.dp_staging, tuple(self.providers),
-                tuple(self.g_provider))
+                self.dp_gating, self.dp_staging, self.nat_any,
+                tuple(self.providers), tuple(self.g_provider))
 
 
 # -- the tick, over K lanes at once ------------------------------------------
@@ -323,6 +328,8 @@ def simulate(lanes: Sequence[Lane], dtype=np.float32) -> List[dict]:
     dt = F(l0.dt)
     thr = np.array(THRESHOLDS, F)
     dp_gating, dp_staging = l0.dp_gating, l0.dp_staging
+    if l0.nat_any:
+        nat_g = st("nat_g")                          # (K,G)
     if dp_gating:
         origin_up = st("origin_up")                  # (K,N,G)
     if dp_staging:
@@ -342,7 +349,7 @@ def simulate(lanes: Sequence[Lane], dtype=np.float32) -> List[dict]:
     fired = np.zeros((K, len(THRESHOLDS)), bool)
     capped, pending = np.zeros(K, bool), np.zeros(K, bool)
     cap_tick = np.full(K, -1, np.int64)
-    pre_ct, fin_ct = z(), z()
+    pre_ct, nat_ct, fin_ct = z(), z(), z()
     accel, busy_h, busy_prov = zf(), zf(), zf(P)
     hit_acc, virgin, egress_g = zf(G), zf(G), zf(G)
     hits, misses, stage_t = zf(), zf(), zf()
@@ -450,6 +457,17 @@ def simulate(lanes: Sequence[Lane], dtype=np.float32) -> List[dict]:
         lv = lv - take_j[:, :L][:, ::-1]
         fresh_q = fresh_q - take_j[:, L]
 
+        # NAT drops: busy pilots of disconnected groups requeue their
+        # jobs at their level; the instance stays live with a dead pilot
+        if l0.nat_any:
+            drop = busy * nat_g[:, :, None]
+            n_drop = drop.sum(axis=(1, 2))
+            lv = lv + requeue_levels(drop)
+            nat_ct = nat_ct + n_drop
+            pre_ct = pre_ct + n_drop
+            busy = busy - drop
+            pdead = pdead + drop.sum(axis=2)
+
         # advance one dt step; finishes release the pilot
         fin = busy * finmask[:, None, :]
         rest = busy - fin
@@ -494,7 +512,7 @@ def simulate(lanes: Sequence[Lane], dtype=np.float32) -> List[dict]:
                             f"for {a.dtype}")
     return [dict(spent=float(spent[b]), accel=float(accel[b]),
                  busy_h=float(busy_h[b]), pre_ct=int(pre_ct[b]),
-                 fin_ct=int(fin_ct[b]),
+                 nat_ct=int(nat_ct[b]), fin_ct=int(fin_ct[b]),
                  egress=float(egress_g[b].astype(np.float32).sum()),
                  stage_t=float(stage_t[b]), hits=float(hits[b]),
                  misses=float(misses[b]), cap_tick=int(cap_tick[b]),
@@ -553,6 +571,7 @@ def lane_row(ln: Lane, end: dict) -> dict:
         "busy_hours": round(end["busy_h"], 1),
         "jobs_finished": end["fin_ct"],
         "preemptions": end["pre_ct"],
+        "nat_drops": end["nat_ct"],
         "egress_usd": round(end["egress"], 2),
         "stagein_hours": round(end["stage_t"] * ln.dt, 1),
         "cache_hit_fraction": round(end["hits"] / seen, 4) if seen else 0.0,

@@ -2,10 +2,11 @@
 
 ``shape`` holds B lanes, G groups, W progress steps (with the staging
 extension where the data plane stages), L checkpoint levels, P
-providers, N ticks, and the variant flags ``dp_gating`` and
-``dp_staging``.  A tick reads and writes its carry once and reads its
-control planes once; the uniforms are drawn in registers.  These are the
-bytes whatever implements the tick: no padding, no one-hot expansion.
+providers, N ticks, and the variant flags ``dp_gating``,
+``dp_staging`` and ``nat_any`` (some group's NAT drops its pilots).  A
+tick reads and writes its carry once and reads its control planes once;
+the uniforms are drawn in registers.  These are the bytes whatever
+implements the tick: no padding, no one-hot expansion.
 The tick is bandwidth-bound: it does a few integer or float operations
 per byte, far below the chip's ratio of peak operations to bandwidth.
 """
@@ -38,6 +39,8 @@ def plane_bytes(s: dict) -> int:
     n += B1 * B + I4 * 5 * B        # outage; floor, downscale, queue
     #                                 level, uncapped and capped target
     n += I4 * 3 * B                 # budget, overhead, finish step
+    if s["nat_any"]:
+        n += I4 * B * G             # NAT-disconnected groups
     if s["dp_gating"]:
         n += B1 * B * G             # origin up
     if s["dp_staging"]:

@@ -1,17 +1,31 @@
 """Byte counts of the tick and of each tick kernel, pinned to hand
 counts at a small shape: B=2 lanes, G=3 groups, W=4 steps, L=2 levels,
-P=2 providers, N=5 ticks."""
+P=2 providers, N=5 ticks; and the shapes the harness works out for the
+``paper`` configuration, plain and with NAT-dropping groups."""
 import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmarks.chip import cells, tickbytes  # noqa: E402
+import pytest  # noqa: E402
+
+from benchmarks.chip import bench, cells, tickbytes  # noqa: E402
 
 PLAIN = {"B": 2, "G": 3, "W": 4, "L": 2, "P": 2, "N": 5,
-         "dp_gating": False, "dp_staging": False}
+         "dp_gating": False, "dp_staging": False, "nat_any": False}
 STAGED = dict(PLAIN, dp_gating=True, dp_staging=True)
+NAT = dict(PLAIN, nat_any=True)
+PAPER = cells.load_json("configs", "paper")
+#: ``paper``'s bytes a tick by lanes a call, as counted before the
+#: harness knew the NAT-drop program: G=10, W=16, L=5, P=3
+PAPER_TICK_BYTES = {32: 57824, 1020: 1843140}
+
+
+def nat_config(conf):
+    """The configuration with OSG's default 5-minute keepalive, past
+    Azure's 4-minute NAT idle timeout."""
+    return dict(conf, spec=dict(conf["spec"], lease_interval_s=300.0))
 
 
 def test_tick_bytes_plain():
@@ -31,6 +45,32 @@ def test_tick_bytes_staged():
     # + origin up 6, miss ticks 24, flush edge 6
     assert tickbytes.plane_bytes(STAGED) == 114 + 36
     assert tickbytes.tick_bytes(STAGED) == 2 * 398 + 150
+
+
+def test_tick_bytes_nat():
+    # the per-lane NAT mask, int32 [B, G], read each tick: 4*2*3 = 24;
+    # the carry already holds the NAT-drop counter
+    assert tickbytes.carry_bytes(NAT) == 302
+    assert tickbytes.plane_bytes(NAT) == 114 + 4 * 2 * 3
+    assert tickbytes.tick_bytes(NAT) - tickbytes.tick_bytes(PLAIN) == 24
+
+
+@pytest.mark.parametrize("lanes", sorted(PAPER_TICK_BYTES))
+def test_paper_tick_bytes_are_pinned(lanes):
+    s = bench.cell_shape(PAPER, lanes)
+    assert s == {"B": lanes, "G": 10, "W": 16, "L": 5, "P": 3, "N": 1344,
+                 "dp_gating": False, "dp_staging": False, "nat_any": False}
+    assert tickbytes.tick_bytes(s) == PAPER_TICK_BYTES[lanes]
+    nat = bench.cell_shape(nat_config(PAPER), lanes)
+    assert tickbytes.tick_bytes(nat) - tickbytes.tick_bytes(s) \
+        == 4 * lanes * s["G"]
+
+
+def test_cell_shape_takes_a_nat_configuration():
+    """Azure's groups reach their NAT timeout; the shapes are the plain
+    configuration's."""
+    assert bench.cell_shape(nat_config(PAPER), 32) == \
+        dict(bench.cell_shape(PAPER, 32), nat_any=True)
 
 
 def test_kernel_bytes():

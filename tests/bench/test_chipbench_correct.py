@@ -2,7 +2,9 @@
 with the program, and a run driven through the harness (its look for a
 chip skipped, at a size a test can hold) comes out not correct with the
 control in the program's place and with each fault that a sweep cell
-can have planted under the timed path.  The cell's own limits apply."""
+can have planted under the timed path.  The cell's own limits apply.
+The NAT-drop program (``nat_any``) is driven through a ``paper`` cell
+whose lease interval outlives Azure's NAT idle timeout."""
 import json
 import sys
 import time
@@ -20,6 +22,10 @@ SEED = 2 ** 31 + 2021        # wider than 32 signed bits, as the driver's
 RESOLVE = cells.resolve      # the cells at full size
 #: each grid axis cut to its first two values, so a test run holds it
 GRID_AXES = 2
+#: OSG's default 5-minute keepalive, past Azure's 4-minute NAT timeout
+NAT_LEASE_S = 300.0
+#: calls that a sound run's window holds at the least
+MIN_CALLS = 2
 
 
 def small(workload, seeds_per_call=4, check_lanes=8):
@@ -32,8 +38,37 @@ def small(workload, seeds_per_call=4, check_lanes=8):
     return c
 
 
+def nat_spec(spec):
+    return dict(spec, lease_interval_s=NAT_LEASE_S)
+
+
+def small_nat(workload):
+    """The cell with the paper's pre-fix keepalive in its spec."""
+    c = small(workload)
+    c["config"] = dict(c["config"], spec=nat_spec(c["config"]["spec"]))
+    return c
+
+
 def drive(workload="paper.seeds-32"):
     return bench.run_cell(workload, SEED, 0.2, False, time.perf_counter())
+
+
+@pytest.fixture
+def nat_cell(monkeypatch):
+    monkeypatch.setattr(cells, "resolve", small_nat)
+
+
+@pytest.fixture
+def min_calls(monkeypatch):
+    """The timed window runs on past its close until it holds
+    ``MIN_CALLS`` calls, however slow a call is on a loaded machine."""
+    loop = bench.Cell._loop
+
+    def loop_on(self, schedule, more, record, timer):
+        return loop(self, schedule,
+                    lambda done: more(done) or len(done) < MIN_CALLS,
+                    record, timer)
+    monkeypatch.setattr(bench.Cell, "_loop", loop_on)
 
 
 @pytest.fixture(autouse=True)
@@ -66,7 +101,7 @@ def fresh_scan():
 
 
 @pytest.mark.parametrize("workload", ["paper.seeds-32", "paper.grid-1020"])
-def test_sound_run_is_correct(workload):
+def test_sound_run_is_correct(workload, min_calls):
     out = drive(workload)
     assert out["correct"], out["checks"]
     assert out["failed"] == 0 and out["attempted"] >= 8
@@ -133,9 +168,46 @@ def test_answer_altered_where_produced_is_not_correct(monkeypatch):
     assert out["checks"]["lanes_off"]["value"] == 1.0
 
 
+def test_sound_nat_run_is_correct(nat_cell, min_calls):
+    out = drive()
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["attempted"] >= 8
+
+
+def test_nat_phase_removed_is_not_correct(monkeypatch, nat_cell):
+    from repro.core import sweep_jax
+    scan_call = sweep_jax.JaxSweepEngine._scan_call
+
+    def no_nat(self):
+        args, kw = scan_call(self)
+        return args, dict(kw, nat_any=False)
+    monkeypatch.setattr(sweep_jax.JaxSweepEngine, "_scan_call", no_nat)
+    out = drive()
+    assert not out["correct"]
+    assert out["checks"]["lanes_off"]["value"] == 1.0
+
+
+def test_nat_drops_counted_as_preemptions_only_is_not_correct(
+        monkeypatch, nat_cell):
+    from repro.core import sweep_jax
+    results = sweep_jax.JaxSweepEngine.lane_results
+
+    def as_preemptions(self, b):
+        res = results(self, b)
+        res["nat_drops"] = 0
+        return res
+    monkeypatch.setattr(sweep_jax.JaxSweepEngine, "lane_results",
+                        as_preemptions)
+    out = drive()
+    assert not out["correct"]
+    assert out["checks"]["lanes_off"]["value"] == 1.0
+
+
 def _lanes(spec_source):
-    if spec_source == "paper":
+    if spec_source in ("paper", "paper-nat"):
         spec = cells.load_json("configs", "paper")["spec"]
+        if spec_source == "paper-nat":
+            spec = nat_spec(spec)
     else:
         spec = json.loads((ROOT / "tests" / "data" / spec_source)
                           .read_text())
@@ -146,7 +218,8 @@ TABLES = cells.load_json("configs", "paper")["catalog_tables"]
 LIMITS = RESOLVE("paper.seeds-32")["limits"]
 
 
-@pytest.mark.parametrize("spec_source", ["paper", "dataplane.spec.json"])
+@pytest.mark.parametrize("spec_source",
+                         ["paper", "dataplane.spec.json", "paper-nat"])
 def test_control_reference_in_lower_precision_differs(spec_source):
     import ml_dtypes
     lanes = _lanes(spec_source)
@@ -157,16 +230,36 @@ def test_control_reference_in_lower_precision_differs(spec_source):
     assert np.isfinite(correct.numbers(want, want)["worst_gap"])
 
 
-def test_reference_matches_the_program_on_the_data_plane_variant():
-    """The reference's stage-in path (the ``dp_gating`` + ``dp_staging``
-    program variant), kept for a data-plane configuration, agrees with
-    the program on the repository's data-plane spec."""
+@pytest.mark.parametrize("spec_source", ["dataplane.spec.json",
+                                         "paper-nat"])
+def test_reference_matches_the_program_on_the_variant(spec_source):
+    """The reference's paths kept for configurations that no cell runs
+    yet agree with the program: the stage-in path (the ``dp_gating`` +
+    ``dp_staging`` variant) on the repository's data-plane spec, and the
+    NAT-drop phase (the ``nat_any`` variant) on the paper's spec with its
+    pre-fix keepalive, where every lane drops pilots."""
     from repro.core import api
     from repro.core.spec import CampaignSpec
-    lanes = _lanes("dataplane.spec.json")
+    lanes = _lanes(spec_source)
     spec = CampaignSpec.from_dict(lanes[0][0])
     rows = api.sweep([spec], [s for _spec, s in lanes], engine="jax").rows
     want = reference.reference_rows(lanes, TABLES)
     ok, checks = correct.judge(
         correct.numbers([correct.slim(r) for r in rows], want), LIMITS)
     assert ok, checks
+    if spec_source == "paper-nat":
+        assert all(r["nat_drops"] > 0 for r in want + rows)
+
+
+def test_reference_rows_of_plain_lanes_are_pinned():
+    """Lanes with no NAT-dropping group step as they did before the
+    reference learned the NAT phase: their rows are byte for byte the
+    pinned ones, with ``nat_drops`` 0 beside them."""
+    pinned = json.loads((ROOT / "tests" / "bench"
+                         / "paper_reference_rows.json").read_text())
+    spec = cells.load_json("configs", "paper")["spec"]
+    rows = reference.reference_rows(
+        [(spec, s) for s in pinned["seeds"]], TABLES)
+    assert [r.pop("nat_drops") for r in rows] == [0] * len(rows)
+    assert [json.dumps(r) for r in rows] == \
+        [json.dumps(r) for r in pinned["rows"]]

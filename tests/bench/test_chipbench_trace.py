@@ -74,7 +74,7 @@ def test_readers_on_the_reduction():
     run = bench.Run()
     run.trace = reduced()
     run.shape = {"B": 2, "G": 3, "W": 4, "L": 2, "P": 2, "N": 5,
-                 "dp_gating": False, "dp_staging": False}
+                 "dp_gating": False, "dp_staging": False, "nat_any": False}
     run.peak = {"hbm_bytes_per_s": 1e12}
     run.kernels = cells.kernel_modules()
     read = {n: cells.load_module("metrics", n).read
